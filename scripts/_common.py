@@ -18,16 +18,10 @@ def cpu_session(n_devices: int = 1, x64: bool = True):
     environment BEFORE this call (backend init snapshots them).
     Returns the configured jax module."""
     sys.path.insert(0, REPO)
-    if n_devices > 1 and "host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        # the portable spelling across jax versions (jax_num_cpu_devices
-        # is newer than the pinned 0.4.37); XLA snapshots XLA_FLAGS at
-        # backend init, which the caller contract says has not happened
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n_devices}")
     import jax
     jax.config.update("jax_platforms", "cpu")
+    if n_devices > 1:
+        jax.config.update("jax_num_cpu_devices", n_devices)
     if n_devices > 1 and len(jax.devices()) < n_devices:
         raise SystemExit(
             f"cpu_session: wanted {n_devices} virtual cpu devices, got "

@@ -57,8 +57,7 @@ def run_micro_bench(nx: int) -> dict:
     """One bench row at gate size, pinned to the CPU backend (the gate
     must not depend on accelerator availability) with a bounded budget."""
     env = dict(os.environ,
-               BENCH_NX=str(nx), BENCH_REPS="2", BENCH_NO_PROBE="1",
-               BENCH_FORCE_CPU="1", BENCH_DEADLINE_S="240",
+               BENCH_NX=str(nx), BENCH_REPS="2", BENCH_DEADLINE_S="240",
                JAX_PLATFORMS="cpu")
     # the gate measures the default configuration — a sweep knob left in
     # the CI environment would silently fork the history key

@@ -68,9 +68,7 @@
 #                   ladder: the bf16 GEMM tier on an ill-conditioned
 #                   gallery matrix passes the componentwise-BERR gate
 #                   or escalates (never delivers a failing X, with and
-#                   without iterative refinement), and the Pallas
-#                   interpret-mode extend-add/assembly path is bitwise
-#                   vs the .at[] lowering per executor
+#                   without iterative refinement)
 #   fleet-failover  scripts/check_fleet_failover.py   serving fleet:
 #                   3 process replicas serving a mixed ≥8-matrix
 #                   stream, kill -9 of one replica mid-stream loses

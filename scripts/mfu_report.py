@@ -38,12 +38,11 @@ from superlu_dist_tpu.utils.peaks import table_peak_gflops  # noqa: E402
 
 
 def _row_mfu(row: dict) -> float:
-    """A row's MFU — recomputed against the per-backend/per-precision
-    peak table (utils/peaks.py; SLU_TPU_PEAK_GFLOPS overrides) whenever
-    the row itself carries none, so legacy rows stop printing the
-    constant-denominator 0.0.  Rows measured on another machine's CPU
-    stay at their recorded value (that machine's peak is unknowable
-    here)."""
+    """A row's MFU — recomputed against the peak table entry of the
+    row's ``device_kind`` (utils/peaks.py; SLU_TPU_PEAK_GFLOPS
+    overrides) whenever the row itself carries none.  Rows without a
+    device_kind (CPU rows, legacy rows) stay at their recorded value:
+    their peak is unknowable here."""
     mfu = row.get("mfu_pct") or 0.0
     if mfu:
         return float(mfu)
@@ -51,9 +50,9 @@ def _row_mfu(row: dict) -> float:
     if not value:
         return 0.0
     peak = env_float("SLU_TPU_PEAK_GFLOPS")
-    if peak <= 0 and row.get("backend") not in (None, "cpu"):
-        peak = table_peak_gflops(row.get("backend", "tpu"),
-                                 row.get("gemm_precision", "highest")) or 0.0
+    if peak <= 0 and row.get("device_kind"):
+        peak = table_peak_gflops(row["device_kind"],
+                                 row.get("gemm_precision", "highest"))
     return round(100.0 * float(value) / peak, 4) if peak > 0 else 0.0
 
 
@@ -145,7 +144,7 @@ def main():
     out = sys.argv[1] if len(sys.argv) > 1 else "tune_results.jsonl"
     err = sys.argv[2] if len(sys.argv) > 2 else "tune_results.err"
     if len(sys.argv) <= 1 and not os.path.exists(out):
-        out, err = "docs/tune_results_r3.jsonl", "docs/tune_results_r3.err"
+        out = "docs/tune_results_r3.jsonl"
 
     missing = []
     rows = []

@@ -13,12 +13,14 @@ of its factor programs from disk and spends ~0 s in `factor-compile`.
 
 This script builds that warm state ahead of need:
 
-  warm_compile_cache.py [--nx N [N ...]] [--dtype D] [--cache-dir DIR]
+  warm_compile_cache.py [--nx N [N ...]] [--dtype D]
       Build the closed plan for poisson3d grids of edge N (default the
       gallery 16 32 48, the BENCH acceptance sizes) with the bench
       blocking, AOT-compile every bucket program into the persistent
       cache, and write a bucket-set warm marker per plan
-      (jaxcache.mark_bucket_set_warm).
+      (jaxcache.mark_bucket_set_warm).  The cache is
+      $JAX_COMPILATION_CACHE_DIR when set, else the checkout's
+      .cache/jax.
 
   warm_compile_cache.py --bundle PATH [--dtype D]
       Same, but for the plan inside a persisted LU handle bundle
@@ -97,16 +99,13 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--bundle", default=None,
                     help="warm the plan of a persisted LU handle instead")
-    ap.add_argument("--cache-dir", default=None,
-                    help="persistent cache dir (default: the repo's "
-                         "machine-scoped .cache/jax-mach-<fp>)")
     args = ap.parse_args(argv)
 
     import jax
     jax.config.update("jax_platforms", "cpu") \
         if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu") else None
     from superlu_dist_tpu.utils.jaxcache import enable_compile_cache
-    enable_compile_cache(args.cache_dir)
+    enable_compile_cache()
 
     if args.bundle:
         from superlu_dist_tpu.persist import load_lu

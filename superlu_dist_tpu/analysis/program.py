@@ -30,14 +30,17 @@ from __future__ import annotations
 
 import dataclasses
 
-#: jaxpr primitives that move data BETWEEN shards.  ``psum`` appears as
-#: ``psum2`` inside shard_map since jax 0.4.31; ``pbroadcast`` is
-#: excluded deliberately — shard_map inserts it as replication
-#: BOOKKEEPING around ordinary math, so counting it would make every
-#: branch look collective-bearing.
+#: jaxpr primitives that move data BETWEEN shards.  Under jax 0.9's
+#: ``jax.shard_map`` a ``psum`` lowers to ``psum_invariant`` when the
+#: varying-axes check is on and to plain ``psum`` with
+#: ``check_vma=False``.  ``pvary`` is excluded deliberately — shard_map
+#: inserts it as varying-axes BOOKKEEPING around ordinary math (a
+#: type cast, no data movement), so counting it would make every branch
+#: look collective-bearing.
 COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
-    "all_to_all", "reduce_scatter", "psum_scatter",
+    "psum", "psum_invariant", "pmax", "pmin", "ppermute", "pshuffle",
+    "all_gather", "all_gather_invariant", "all_to_all", "reduce_scatter",
+    "psum_scatter",
 })
 
 #: control-flow primitives whose branch sub-jaxprs execute ALTERNATIVELY

@@ -73,11 +73,11 @@ class LUFactorization:
     dev_solver: object = None          # lazy DeviceSolver (SolveInitialized
                                        # analog, pdgssvx.c:1330-1337)
     solve_path: str = "auto"           # "auto" | "host" | "device"; "auto"
-                                       # falls back to host if the device
-                                       # solve ever fails (robustness over
-                                       # crash — the pdtest harness survives
-                                       # partial failures, TEST/pdtest.c)
-    solve_fallback_reason: str = None  # why the device path was abandoned
+                                       # resolves on the first solve from
+                                       # the regime (backend, mesh, factor
+                                       # residency) and records the path
+                                       # taken — a device-solve failure
+                                       # raises, it never swaps to host
     mesh: object = None                # the grid mesh the factors are
                                        # sharded over (None off-grid).  When
                                        # it spans multiple PROCESSES the
@@ -165,11 +165,8 @@ class LUFactorization:
             lambda: lu_solve_trans(self.numeric, d, conj=conj))
 
     def _dispatch_solve(self, device_call, host_call):
-        """Shared device-vs-host solve dispatch with the auto-fallback
-        discipline (one copy — the plain and transpose paths must never
-        drift)."""
-        import warnings
-
+        """Shared device-vs-host solve dispatch (one copy — the plain and
+        transpose paths must never drift)."""
         import jax
         # a mesh spanning multiple processes means no process holds the
         # whole factor: the solve MUST run collectively on the mesh (and
@@ -188,8 +185,7 @@ class LUFactorization:
         # a SINGLE-process mesh routes to the shard_map SPMD tier
         # (parallel/spmd.SpmdSolver): the whole fwd+bwd sweep is ONE
         # compiled program per nrhs bucket, bitwise-identical to the
-        # local DeviceSolver (so the lockstep fallback below stays a
-        # valid recovery path)
+        # local DeviceSolver
         spmd = False
         if (self.mesh is not None and not multiproc
                 and self.solve_path != "host"
@@ -197,45 +193,36 @@ class LUFactorization:
             from superlu_dist_tpu.parallel.spmd import spmd_mode
             spmd = spmd_mode()
             use_device = use_device or spmd
-        if use_device:
-            try:
-                if self.dev_solver is None:
-                    if spmd:
-                        from superlu_dist_tpu.parallel.spmd import SpmdSolver
-                        self.dev_solver = SpmdSolver(
-                            self.numeric, self.mesh,
-                            schedule=self.options.solve_schedule,
-                            window=self.options.solve_window,
-                            align=self.options.solve_align,
-                            gemm_prec=getattr(self.options, "gemm_prec",
-                                              None))
-                    else:
-                        from superlu_dist_tpu.solve.device import DeviceSolver
-                        # multiproc: streamed sweeps (fused=False) — the
-                        # whole-sweep programs at n≈1e5 hit the same compile
-                        # wall as the fused factor executor (see
-                        # factor.get_executor's auto rule)
-                        self.dev_solver = DeviceSolver(
-                            self.numeric, diag_inv=self.options.diag_inv,
-                            mesh=self.mesh if multiproc else None,
-                            fused=False if multiproc else "auto",
-                            schedule=self.options.solve_schedule,
-                            window=self.options.solve_window,
-                            align=self.options.solve_align,
-                            gemm_prec=getattr(self.options, "gemm_prec",
-                                              None))
-                return device_call(self.dev_solver)
-            except Exception as e:
-                if self.solve_path != "auto" or multiproc:
-                    raise
-                # device path failed — permanently fall back to the host
-                # solve for this factorization rather than crash the run
-                self.solve_path = "host"
-                self.solve_fallback_reason = f"{type(e).__name__}: {e}"
-                warnings.warn("device solve failed; falling back to host "
-                              f"solve ({self.solve_fallback_reason})",
-                              RuntimeWarning, stacklevel=3)
-        return host_call()
+        if self.solve_path == "auto":
+            # backend, mesh and factor residency are fixed for a handle,
+            # so the regime's choice is recorded once
+            self.solve_path = "device" if use_device else "host"
+        if not use_device:
+            return host_call()
+        if self.dev_solver is None:
+            if spmd:
+                from superlu_dist_tpu.parallel.spmd import SpmdSolver
+                self.dev_solver = SpmdSolver(
+                    self.numeric, self.mesh,
+                    schedule=self.options.solve_schedule,
+                    window=self.options.solve_window,
+                    align=self.options.solve_align,
+                    gemm_prec=getattr(self.options, "gemm_prec", None))
+            else:
+                from superlu_dist_tpu.solve.device import DeviceSolver
+                # multiproc: streamed sweeps (fused=False) — the
+                # whole-sweep programs at n≈1e5 hit the same compile wall
+                # as the fused factor executor (see factor.get_executor's
+                # auto rule)
+                self.dev_solver = DeviceSolver(
+                    self.numeric, diag_inv=self.options.diag_inv,
+                    mesh=self.mesh if multiproc else None,
+                    fused=False if multiproc else "auto",
+                    schedule=self.options.solve_schedule,
+                    window=self.options.solve_window,
+                    align=self.options.solve_align,
+                    gemm_prec=getattr(self.options, "gemm_prec", None))
+        return device_call(self.dev_solver)
 
     def _solve_permuted(self, d: np.ndarray) -> np.ndarray:
         return self._dispatch_solve(lambda s: s.solve(d),
@@ -830,12 +817,8 @@ def _escalation_dtype(cur) -> str | None:
     if cur in ("float64", "complex128") or "df64" in cur:
         return None
     import jax
-    if jax.default_backend() == "cpu":
-        try:
-            if jax.config.read("jax_enable_x64"):
-                return "float64"
-        except Exception:
-            pass
+    if jax.default_backend() == "cpu" and jax.config.read("jax_enable_x64"):
+        return "float64"
     return "df64"
 
 
@@ -1114,12 +1097,16 @@ def _solve_and_refine(options: Options, a: SparseCSR, b: np.ndarray,
                           if options.iter_refine == IterRefine.SLU_SINGLE
                           else np.dtype(options.ir_dtype))
         # device-resident residual SpMV (pdgsmv analog, SRC/pdgsmv.c:234)
-        # when an accelerator is present and A is big enough for the
-        # upload to pay for itself; host numpy otherwise or on failure
+        # when an accelerator is present, A is big enough for the upload
+        # to pay for itself and x64 is on (a 64-bit residual needs it);
+        # host numpy otherwise.  A device SpMV that fails raises.
         ir_op = op
         import jax
+        stats.ir_residual = "host"
         if (jax.default_backend() != "cpu"
-                and op.nnz >= 100_000 and not lu.numeric.on_host):
+                and op.nnz >= 100_000 and not lu.numeric.on_host
+                and jax.config.read("jax_enable_x64")):
+            stats.ir_residual = "device"
             # cached per (trans, residual dtype) on the factorization —
             # the pdgsmv_init / SOLVEstruct discipline (SRC/pdgsmv.c:31).
             # The hit is guarded by data-array identity: FACTORED reuse
@@ -1134,13 +1121,9 @@ def _solve_and_refine(options: Options, a: SparseCSR, b: np.ndarray,
             hit = cache.get(key)
             ir_op = hit[1] if hit is not None and hit[0] is a.data else None
             if ir_op is None:
-                try:
-                    from superlu_dist_tpu.parallel.dist import DeviceSpMV
-                    ir_op = DeviceSpMV(
-                        op,
-                        dtype=np.result_type(op.data.dtype, residual_dtype))
-                except Exception:          # x64 off / upload failure —
-                    ir_op = op             # host residual stays correct
+                from superlu_dist_tpu.parallel.dist import DeviceSpMV
+                ir_op = DeviceSpMV(
+                    op, dtype=np.result_type(op.data.dtype, residual_dtype))
                 cache[key] = (a.data, ir_op)
                 lu.dev_spmv = cache
         with stats.timer("REFINE"):

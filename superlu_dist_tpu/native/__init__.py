@@ -2,9 +2,14 @@
 
 The reference's host analysis is C (SRC/etree.c, symbfact.c, mc64ad_dist.c,
 get_perm_c.c); ours is C++ compiled on first use with the toolchain baked
-into the image.  Python implementations remain the specification and the
-fallback: every entry point here degrades gracefully when the compiler is
-unavailable, and the test suite cross-checks native vs Python output.
+into the image.  The built library is named by a hash of the committed
+source (``_slu_host-<sha>.so``), so a library built from any other
+source is never loaded.  Python implementations remain the
+specification and the fallback for library callers: every entry point
+here degrades when the compiler is unavailable, and the test suite
+cross-checks native vs Python output.  :func:`require` is the strict
+entry for callers that must not degrade (chip_smoke.py): it raises with
+the compiler's own error.
 
 Set SLU_TPU_NO_NATIVE=1 to force the Python fallbacks.
 """
@@ -12,6 +17,7 @@ Set SLU_TPU_NO_NATIVE=1 to force the Python fallbacks.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,62 +28,60 @@ from superlu_dist_tpu.utils.lockwatch import make_lock
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "slu_host.cpp")
-_LIB = os.path.join(_HERE, "_slu_host.so")
 
 _lock = make_lock("native._lock")
 _lib = None
 _tried = False
+_error = None              # why the last load failed (None: not failed)
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _F64 = ctypes.POINTER(ctypes.c_double)
 
 
-def _build(force: bool = False) -> str | None:
-    """Compile the shared library if missing or stale; return path or None."""
-    try:
-        if (not force and os.path.exists(_LIB)
-                and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-            return _LIB
-        # per-process tmp name: concurrent first-use builds (pytest workers,
-        # bench + tests) must not interleave writes; os.replace is atomic
-        # (-lrt: shm_open lives in librt on glibc < 2.34; a no-op stub on
-        # newer glibc, so linking it unconditionally is safe)
-        tmp = f"{_LIB}.{os.getpid()}.tmp"
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-             "-o", tmp, _SRC, "-lrt"],
-            check=True, capture_output=True, timeout=300)
-        os.replace(tmp, _LIB)
-        return _LIB
-    except Exception:
-        return None
+def lib_path() -> str:
+    """The library built from the current source: its name carries the
+    first 16 hex digits of the source's sha256."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_slu_host-{digest}.so")
+
+
+def _build(path: str) -> str:
+    """Compile the shared library to ``path`` (``lib_path()``) unless it
+    exists; return the path.  Raises ``subprocess.CalledProcessError``
+    (with the compiler's stderr) or ``OSError`` when it cannot be
+    built."""
+    if os.path.exists(path):
+        return path
+    # per-process tmp name: concurrent first-use builds (pytest workers,
+    # bench + tests) must not interleave writes; os.replace is atomic
+    # (-lrt: shm_open lives in librt on glibc < 2.34; a no-op stub on
+    # newer glibc, so linking it unconditionally is safe)
+    tmp = f"{path}.{os.getpid()}.tmp"
+    subprocess.run(
+        ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
+         "-o", tmp, _SRC, "-lrt"],
+        check=True, capture_output=True, text=True, timeout=300)
+    os.replace(tmp, path)
+    return path
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _error
     if _lib is not None or _tried:
         return _lib
+    path = lib_path()            # reads the source: outside the lock
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
+        _error = None
         from superlu_dist_tpu.utils.options import env_flag
         if env_flag("SLU_TPU_NO_NATIVE"):
-            return None
-        path = _build()
-        if path is None:
+            _error = "SLU_TPU_NO_NATIVE is set"
             return None
         try:
-            try:
-                lib = ctypes.CDLL(path)
-            except OSError:
-                # a stale .so built for a different libc (e.g. shm_open
-                # moved between librt and libc) loads nowhere — rebuild
-                # against THIS toolchain and retry once
-                path = _build(force=True)
-                if path is None:
-                    return None
-                lib = ctypes.CDLL(path)
+            lib = ctypes.CDLL(_build(path))
             lib.slu_etree.argtypes = [ctypes.c_int64, _I64, _I64, _I64]
             lib.slu_postorder.argtypes = [ctypes.c_int64, _I64, _I64]
             # (slu_symbolic — the serial alias — stays exported for the C
@@ -153,13 +157,24 @@ def _load():
                 ctypes.c_int64, ctypes.c_int64, _I64, _I64, ctypes.c_int64,
                 _I64, ctypes.POINTER(_I64)]
             _lib = lib
-        except Exception:
-            _lib = None
+        except subprocess.CalledProcessError as e:
+            _error = f"g++ failed ({e.returncode}): {e.stderr.strip()}"
+        except Exception as e:
+            _error = f"{type(e).__name__}: {e}"
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def require():
+    """The loaded library, or RuntimeError naming why it could not be
+    built or loaded — for callers that must run the native analysis."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(f"native host library unavailable: {_error}")
+    return lib
 
 
 def _as_i64(a: np.ndarray) -> np.ndarray:

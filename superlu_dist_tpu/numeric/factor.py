@@ -31,42 +31,68 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from superlu_dist_tpu.numeric.plan import FactorPlan
+from superlu_dist_tpu.numeric.plan import (LANES, FactorPlan, front_dims,
+                                           lane_pad, pool_block)
 from superlu_dist_tpu.obs.trace import get_tracer
 from superlu_dist_tpu.ops.dense import group_partial_factor
 
 
-def extend_add_set(f, pool, m, ub, child_off, child_slot, rel):
+def extend_add_set(fx, pool, m, ub, child_off, child_slot, rel):
     """One child-set's extend-add: gather each child's padded ub×ub Schur
     block from the pool and scatter-add it into the parent fronts at
-    rel[c,i]·m + rel[c,j] (rel == m is the OOB sentinel).  SHARED
-    MACHINERY: ``group_step`` unrolls a Python loop of these per group
-    (one call per ChildSet), and the mega executor (numeric/mega.py)
-    lax.scan's the SAME function over uniform padded child tables with a
-    TRACED ``ub`` — keep it shape-polymorphic in (C, UB) and exact in
-    the per-child gather indices (off + i·ub + j), which is what makes
-    the two executors bitwise-identical."""
+    (rel[c,i], rel[c,j]).  SHARED MACHINERY: ``group_step`` unrolls a
+    Python loop of these per group (one call per ChildSet), and the mega
+    executor (numeric/mega.py) lax.scan's the SAME function over uniform
+    padded child tables with a TRACED ``ub`` — keep it shape-polymorphic
+    in (C, UB) and exact in the per-child gather indices
+    (off + i·lane_pad(ub) + j), which is what makes the two executors
+    bitwise-identical.
+
+    ``fx`` is the batch of fronts FLAT in the dump layout of
+    ``group_step`` (``front_dims``): the rel sentinel (== m) lands in the
+    dump row/column, discarded afterwards.  The scatter indices are
+    non-decreasing — a set's children are in ascending slot order
+    (numeric/plan.py), each child's rel row ascends to its sentinels,
+    the lane padding of a pool block maps to the sentinel too, and a
+    padded child (slot == batch) lies past the end and is dropped — so
+    the scatter is declared ``indices_are_sorted``.  The index arrays
+    are built as (C, UB, lane_pad(UB)) and merged along the lane-aligned
+    minor axis: merging a (C, UB, UB) array costs the TPU compiler a
+    relayout of ~25 s at UB ≈ 2700, index arrays derived from one long
+    iota get folded into constants at compile time, and an unsorted
+    scatter costs ~15 s on its own."""
     c, ubmax = rel.shape
-    ii = jnp.arange(ubmax)
-    # per-child 2-D gather: row stride is the child's REAL ub (a python
-    # int here, the per-set bucket in the mega scan), so entries past a
-    # child's real block read out of its pool slab — always paired with
-    # an OOB rel sentinel, hence dropped below
-    src = (child_off[:, None, None] + ii[None, :, None] * ub
-           + ii[None, None, :]).reshape(c, ubmax * ubmax)
-    vals = pool.at[src].get(mode="fill", fill_value=0)
-    ri, rj = rel[:, :, None], rel[:, None, :]
-    # any sentinel (rel == m) in the pair must push the flat index OOB —
-    # a mixed pair's ri*m + rj would land in-bounds at (ri+1, 0)
-    dst = jnp.where((ri >= m) | (rj >= m), m * m,
-                    ri * m + rj).reshape(c, ubmax * ubmax)
-    return f.at[(child_slot[:, None], dst)].add(vals, mode="drop")
+    rows, cols = front_dims(m)
+    stride = lane_pad(ubmax)
+    if isinstance(ub, int) and ub == ubmax:
+        # static block size: each child's block is one contiguous pool
+        # slab, read as a slice (a slot past the pool clamps — always a
+        # padded child whose scatter below is dropped)
+        vals = jax.vmap(
+            lambda o: jax.lax.dynamic_slice(pool, (o,), (ub * stride,)))(
+                child_off)
+    else:
+        # per-child gather at the child's REAL row stride (the per-set
+        # bucket in the mega scan), so entries past a child's real block
+        # read out of its pool slab — always paired with a rel
+        # sentinel, hence dumped below
+        row_stride = -(-ub // LANES) * LANES       # lane_pad, traced
+        src = (child_off[:, None, None]
+               + jnp.arange(ubmax)[:, None] * row_stride
+               + jnp.arange(stride)).reshape(c, ubmax * stride)
+        vals = pool.at[src].get(mode="fill", fill_value=0)
+    rel_cols = jnp.pad(rel, ((0, 0), (0, stride - ubmax)),
+                       constant_values=m)
+    idx = (child_slot[:, None, None] * (rows * cols)
+           + rel[:, :, None] * cols + rel_cols[:, None, :])
+    return fx.at[idx.reshape(c, ubmax * stride)].add(
+        vals, mode="drop", indices_are_sorted=True)
 
 
 def group_step(dims, avals, pool, thresh, a_slot, a_flat, a_src, ws, off,
                children, front_sharding=None, pivot_sharding=None,
                replicated=None, pivot="blocked", gemm_prec="highest",
-               pallas="off", write_back=True):
+               write_back=True):
     """One (level, bucket) group: assemble + factor + write back.
 
     dims = (batch, m, w, u) static; `children` is either a list of
@@ -78,76 +104,66 @@ def group_step(dims, avals, pool, thresh, a_slot, a_flat, a_src, ws, off,
     Index padding convention (used by the streamed executor): scatter
     slots == batch and gather sources past the array end are
     dropped/filled — all index arithmetic keeps OOB entries OOB (rel
-    sentinel == m maps past m*m).
+    sentinel == m lands in the discarded dump row/column).
 
-    ``gemm_prec`` is the caller-resolved GEMM-precision ladder tier and
-    ``pallas`` the resolved fused-kernel mode (numeric/pallas_kernels):
-    both are baked into the cached jitted factories' keys, never read
-    from env here (slulint SLU102/SLU105).  The Pallas path is bitwise-
-    identical to the ``.at[]`` lowering, so every executor-equivalence
-    contract is mode-independent — including under a mesh, where the
-    SPMD tier runs it per-shard inside shard_map (interpret mode on CPU
-    meshes, native on TPU; see parallel/spmd.py).
+    Every scatter into the fronts declares its indices sorted (see
+    extend_add_set): the A-entry maps arrive sorted by (slot, position)
+    from the plan, padding past the end.
+
+    ``gemm_prec`` is the caller-resolved GEMM-precision ladder tier,
+    baked into the cached jitted factories' keys, never read from env
+    here (slulint SLU102/SLU105).
 
     ``write_back=False`` (the SPMD per-shard path) skips the pool
-    scatter and returns the raw (batch, u*u) Schur values in the pool's
-    position instead (None when u == 0): inside shard_map each device
-    factors only its slot partition, so the full-order pool write is
-    replayed by the caller AFTER the all-gather — keeping the exact
-    scatter sequence (and hence bitwise factors) of the write_back=True
-    lowering every other executor runs.
+    write and returns the raw (batch, pool_block(u)) Schur blocks in the
+    pool's position instead (None when u == 0): inside shard_map each
+    device factors only its slot partition, so the full-order pool
+    write is replayed by the caller AFTER the all-gather — keeping the
+    exact write sequence (and hence bitwise factors) of the
+    write_back=True lowering every other executor runs.
     """
     batch, m, w, u = dims
     dt = pool.dtype
     wsc = jax.lax.with_sharding_constraint
-    use_pallas = pallas in ("on", "interpret")
+    rows, cols = front_dims(m)
 
-    f = jnp.zeros((batch, m * m), dtype=dt)
+    # fronts flat in the dump layout (extend_add_set)
+    fx = jnp.zeros((batch * rows * cols,), dtype=dt)
     if replicated is not None:
-        f = wsc(f, replicated)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        fx = wsc(fx, NamedSharding(replicated.mesh, P()))
     # identity columns for pivot-block padding (cols ws..w), computed on
     # device so padded batch slots (ws == 0) become identity fronts
     k = jnp.arange(m)
     diag_mask = (k[None, :] >= ws[:, None]) & (k[None, :] < w)
-    f = f.at[:, k * m + k].add(diag_mask.astype(dt))
+    # one flat index vector: under a mesh, XLA's SPMD partitioner
+    # splits a (batch, m) index array along the batch axis and
+    # miscompiles the scatter (jax 0.9; tests/test_parallel.py)
+    diag = (jnp.arange(batch)[:, None] * (rows * cols)
+            + k * (cols + 1)).reshape(-1)
+    fx = fx.at[diag].add(diag_mask.astype(dt).reshape(-1),
+                         indices_are_sorted=True, unique_indices=True)
     if a_src.shape[0]:
-        f2 = None
-        if use_pallas:
-            from superlu_dist_tpu.numeric.pallas_kernels import (
-                assemble_avals_pallas)
-            f2 = assemble_avals_pallas(f, avals, a_slot, a_flat, a_src,
-                                       mode=pallas)
-        if f2 is not None:
-            f = f2
-        else:
-            vals = avals.at[a_src].get(mode="fill", fill_value=0)
-            f = f.at[(a_slot, a_flat)].add(vals, mode="drop")
+        vals = avals.at[a_src].get(mode="fill", fill_value=0)
+        # a_flat is the position in the (m, m) front; padding entries
+        # (slot == batch) land past the end and are dropped
+        idx = a_slot * (rows * cols) + (a_flat // m) * cols + a_flat % m
+        fx = fx.at[idx].add(vals, mode="drop", indices_are_sorted=True,
+                            unique_indices=True)
     if isinstance(children, tuple):
         # stacked child tables (mega executor): scan the shared per-set
         # extend-add — the sets fold into f in the same sequence the
         # Python loop below runs them, so the factors stay bitwise equal
-        # (the per-set ub is TRACED here, so this branch keeps the .at[]
-        # lowering under every pallas mode)
         c_off, c_slot, c_ub, c_rel = children
         if c_off.shape[0]:
             def body(fc, xs):
                 co, cs, ub, r = xs
                 return extend_add_set(fc, pool, m, ub, co, cs, r), None
-            f, _ = jax.lax.scan(body, f, (c_off, c_slot, c_ub, c_rel))
+            fx, _ = jax.lax.scan(body, fx, (c_off, c_slot, c_ub, c_rel))
     else:
         for (ub, child_off, child_slot, rel) in children:
-            f2 = None
-            if use_pallas:
-                from superlu_dist_tpu.numeric.pallas_kernels import (
-                    extend_add_set_pallas)
-                f2 = extend_add_set_pallas(f, pool, m, ub, child_off,
-                                           child_slot, rel, mode=pallas)
-            if f2 is not None:
-                f = f2
-            else:
-                f = extend_add_set(f, pool, m, ub, child_off, child_slot,
-                                   rel)
-    f = f.reshape(batch, m, m)
+            fx = extend_add_set(fx, pool, m, ub, child_off, child_slot, rel)
+    f = fx.reshape(batch, rows, cols)[:, :m, :m]
     if front_sharding is not None:
         f = wsc(f, front_sharding)
     lpanel, upanel, schur, counts = group_partial_factor(
@@ -158,16 +174,34 @@ def group_step(dims, avals, pool, thresh, a_slot, a_flat, a_src, ws, off,
     # pivots — don't let a thresh > 1 count them as tiny
     tiny = jnp.sum(jnp.where(jnp.arange(w)[None, :] < ws[:, None], counts, 0))
     if u > 0:
-        vals = schur.reshape(batch, u * u)
+        # the pool block layout (plan.pool_block): lane-padded rows
+        vals = jnp.pad(schur, ((0, 0), (0, 0), (0, lane_pad(u) - u)))
+        vals = vals.reshape(batch, pool_block(u))
         if replicated is not None:
             vals = wsc(vals, replicated)
         if not write_back:
             return (lpanel, upanel), vals, tiny
-        dst = off[:, None] + jnp.arange(u * u)         # off==pool_size drops
-        pool = pool.at[dst].set(vals, mode="drop")
+        pool = pool_write(pool, off, vals)
     elif not write_back:
         return (lpanel, upanel), None, tiny
     return (lpanel, upanel), pool, tiny
+
+
+def pool_write(pool, off, vals):
+    """Write slot s's Schur block ``vals[s]`` into the pool at ``off[s]``
+    — one contiguous slice per slot, which the TPU compiler lowers in
+    well under a second where the equivalent element scatter costs
+    ~15 s.  A slot whose offset lies past the pool (padding) leaves the
+    pool unchanged."""
+    size, n = pool.shape[0], vals.shape[1]
+
+    def body(s, pool):
+        o = off[s]
+        cur = jax.lax.dynamic_slice(pool, (o,), (n,))
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(o < size, vals[s], cur), (o,))
+
+    return jax.lax.fori_loop(0, vals.shape[0], body, pool)
 
 
 def pool_spec(mesh, pool_partition: bool):
@@ -212,6 +246,8 @@ class NumericFactorization:
     resumed_groups: int = 0   # dispatch groups restored from a durable
                               # checkpoint frontier instead of recomputed
                               # (persist/checkpoint.py; 0 = fresh run)
+    executor: str = ""        # class name of the factor executor that
+                              # ran (StreamExecutor, SpmdFactorExecutor…)
     gemm_prec: str = "highest"  # GEMM-precision ladder tier the Schur
                               # updates ran at (ops/dense.gemm_precision)
                               # — recorded so the BERR gate / escalation
@@ -238,8 +274,7 @@ class NumericFactorization:
 
 
 def make_factor_fn(plan: FactorPlan, dtype="float64", mesh=None,
-                   pool_partition: bool = False, gemm_prec=None,
-                   pallas=None):
+                   pool_partition: bool = False, gemm_prec=None):
     """Build the whole numeric factorization as ONE jittable function.
 
     Returns fn(avals, thresh) -> (fronts_tuple, tiny_count).  The plan's
@@ -285,17 +320,13 @@ def make_factor_fn(plan: FactorPlan, dtype="float64", mesh=None,
         for (_, child_off, child_slot, rel) in children:
             flat_args.extend((child_off, child_slot, rel))
     flat_args = tuple(flat_args)
-    # SLU_TPU_PIVOT_KERNEL / SLU_TPU_GEMM_PREC / SLU_TPU_PALLAS resolved
-    # HERE, in the uncached factory, and closed over as constants —
-    # get_executor keys the fused executor on them, and the traced body
-    # must not read env (slulint SLU102/SLU105).  Mesh runs no longer
-    # pin Pallas off: the resolved mode rides through (auto still means
-    # off on CPU backends, interpret/on must be asked for explicitly).
-    from superlu_dist_tpu.numeric.pallas_kernels import pallas_mode
+    # SLU_TPU_PIVOT_KERNEL / SLU_TPU_GEMM_PREC resolved HERE, in the
+    # uncached factory, and closed over as constants — get_executor keys
+    # the fused executor on them, and the traced body must not read env
+    # (slulint SLU102/SLU105)
     from superlu_dist_tpu.ops.dense import gemm_precision, pivot_kernel
     pivot = pivot_kernel()
     gemm_prec = gemm_precision(gemm_prec)
-    pallas = pallas_mode(pallas)
 
     def fn(avals, thresh, *flat):
         avals = avals.astype(dtype)
@@ -316,8 +347,7 @@ def make_factor_fn(plan: FactorPlan, dtype="float64", mesh=None,
                 (grp.batch, grp.m, grp.w, grp.u), avals, pool, thresh,
                 a_slot, a_flat, a_src, ws, off, children,
                 front_sharding=sharding, pivot_sharding=pivot_sharding,
-                replicated=replicated, pivot=pivot, gemm_prec=gemm_prec,
-                pallas=pallas)
+                replicated=replicated, pivot=pivot, gemm_prec=gemm_prec)
             if mesh is not None:
                 pool = jax.lax.with_sharding_constraint(pool, pool_sharding)
             fronts.append(packed)
@@ -385,6 +415,7 @@ def make_factor_fn(plan: FactorPlan, dtype="float64", mesh=None,
                                                          1.0), 4))
         return out
 
+    traced.executor_name = "make_factor_fn"
     return traced
 
 
@@ -396,7 +427,7 @@ def get_executor(plan: FactorPlan, dtype="float64", executor: str = "auto",
     plan size), "stream" (per-bucket kernels — compile count is bounded,
     right for real TPU where program compile is expensive), "mega"
     (bucketed shape-closed programs, O(1) compile count), "spmd" (the
-    shard_map tier, parallel/spmd.py: ONE compiled program per factor
+    shard_map tier, parallel/spmd.py: one compiled program per group
     with the collectives as in-program ops), or "auto".  Auto picks
     spmd on a single-process mesh (unless SLU_TPU_SPMD=0 or the pool is
     partitioned), stream on multi-process meshes and accelerators, and
@@ -429,19 +460,17 @@ def get_executor(plan: FactorPlan, dtype="float64", executor: str = "auto",
     cache = getattr(plan, "_factor_fns", None)
     if cache is None:
         cache = plan._factor_fns = {}
-    from superlu_dist_tpu.numeric.pallas_kernels import pallas_mode
     from superlu_dist_tpu.ops.dense import gemm_precision, pivot_kernel
     from superlu_dist_tpu.utils.options import env_float
-    # every executor bakes the GEMM-precision tier and the Pallas mode
-    # into its compiled programs, so both are part of its identity (the
-    # escalation rung's refactor-at-the-next-tier relies on getting a
-    # FRESH executor); the fused executor additionally bakes the
-    # pivot-kernel choice, which StreamExecutor re-reads per call
-    # (stream._kernel / _level_fns key on it)
+    # every executor bakes the GEMM-precision tier into its compiled
+    # programs, so it is part of its identity (the escalation rung's
+    # refactor-at-the-next-tier relies on getting a FRESH executor); the
+    # fused executor additionally bakes the pivot-kernel choice, which
+    # StreamExecutor re-reads per call (stream._kernel / _level_fns key
+    # on it)
     gemm_prec = gemm_precision(gemm_prec)
-    pallas = pallas_mode()
     key = (str(jnp.dtype(dtype)), executor, mesh, bool(pool_partition),
-           gemm_prec, pallas,
+           gemm_prec,
            pivot_kernel() if executor == "fused" else None,
            # StreamExecutor latches the host-share threshold at
            # construction — a changed SLU_TPU_HOST_FLOPS needs a new one
@@ -453,20 +482,20 @@ def get_executor(plan: FactorPlan, dtype="float64", executor: str = "auto",
             from superlu_dist_tpu.numeric.stream import StreamExecutor
             fn = StreamExecutor(plan, dtype, mesh=mesh,
                                 pool_partition=pool_partition,
-                                gemm_prec=gemm_prec, pallas=pallas)
+                                gemm_prec=gemm_prec)
         elif executor == "mega":
             from superlu_dist_tpu.numeric.mega import MegaExecutor
             fn = MegaExecutor(plan, dtype, mesh=mesh,
                               pool_partition=pool_partition,
-                              gemm_prec=gemm_prec, pallas=pallas)
+                              gemm_prec=gemm_prec)
         elif executor == "spmd":
             from superlu_dist_tpu.parallel.spmd import SpmdFactorExecutor
             fn = SpmdFactorExecutor(plan, dtype, mesh,
-                                    gemm_prec=gemm_prec, pallas=pallas)
+                                    gemm_prec=gemm_prec)
         else:
             fn = make_factor_fn(plan, dtype, mesh=mesh,
                                 pool_partition=pool_partition,
-                                gemm_prec=gemm_prec, pallas=pallas)
+                                gemm_prec=gemm_prec)
         cache[key] = fn
     return fn
 
@@ -614,6 +643,8 @@ def numeric_factorize(plan: FactorPlan, pattern_values: np.ndarray,
                                 finite=finite, info_col=info_col,
                                 resumed_groups=(resume.k if resume is not None
                                                 else 0),
+                                executor=getattr(fn, "executor_name",
+                                                 type(fn).__name__),
                                 gemm_prec=gemm_prec)
 
 

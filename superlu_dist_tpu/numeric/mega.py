@@ -72,18 +72,15 @@ _STORE_GROWTH = 1.25
 
 @functools.lru_cache(maxsize=None)
 def _mega_kernel(dims, la, child_dims, pool_len, avals_len, dtype, pivot,
-                 gemm_prec="highest", pallas="off", mesh=None,
+                 gemm_prec="highest", mesh=None,
                  pool_partition=False):
     """ONE jitted program for a closed shape bucket.
 
     Everything per-group — which fronts, which A entries, which children
     — arrives as device-array arguments at canonical shapes; the program
-    itself is pure dataflow.  `pivot`/`gemm_prec`/`pallas` are the
-    caller-resolved SLU_TPU_PIVOT_KERNEL / SLU_TPU_GEMM_PREC /
-    SLU_TPU_PALLAS choices (part of this cache key — slulint SLU105).
-    The stacked-children extend-add keeps the .at[] scan under every
-    pallas mode (its per-set ub is traced); the A-assembly takes the
-    fused path — bitwise-identical either way.  With a mesh, the dense
+    itself is pure dataflow.  `pivot`/`gemm_prec` are the
+    caller-resolved SLU_TPU_PIVOT_KERNEL / SLU_TPU_GEMM_PREC choices
+    (part of this cache key — slulint SLU105).  With a mesh, the dense
     math shards exactly like stream._kernel (batch-over-"snode",
     columns-over-"panel", pool via factor.pool_spec)."""
     batch, m, w, u = dims
@@ -106,7 +103,7 @@ def _mega_kernel(dims, la, child_dims, pool_len, avals_len, dtype, pivot,
             (child_off, child_slot, child_ub, rel),
             front_sharding=front_sharding, pivot_sharding=pivot_sharding,
             replicated=replicated, pivot=pivot,
-            gemm_prec=gemm_prec, pallas=pallas)
+            gemm_prec=gemm_prec)
         if pool_sharding is not None:
             pool = jax.lax.with_sharding_constraint(pool, pool_sharding)
         return out, pool, tiny
@@ -137,7 +134,7 @@ class MegaExecutor(StreamExecutor):
 
     def __init__(self, plan: FactorPlan, dtype="float64", mesh=None,
                  offload: str = "auto", pool_partition: bool = False,
-                 host_flops=None, gemm_prec=None, pallas=None):
+                 host_flops=None, gemm_prec=None):
         self._mega_fns = {}
         self._spec = {}
         # host-share is off by construction: the per-bucket programs are
@@ -146,7 +143,7 @@ class MegaExecutor(StreamExecutor):
         super().__init__(plan, dtype, mesh=mesh, offload=offload,
                          pool_partition=pool_partition,
                          granularity="group", host_flops=0.0,
-                         gemm_prec=gemm_prec, pallas=pallas)
+                         gemm_prec=gemm_prec)
         self.granularity = "mega"
 
     # ---- canonical metadata packing -------------------------------------
@@ -205,6 +202,11 @@ class MegaExecutor(StreamExecutor):
                           grp.batch, False))
         return steps
 
+    def _build_kernels(self, pivot, avals, pool, thresh) -> None:
+        """Nothing ahead of the stream: each bucket program is AOT-staged
+        by ``_get_kernel`` on first use (``prebake`` warms the whole set
+        without a factorization)."""
+
     # ---- AOT program acquisition + census -------------------------------
     def _get_kernel(self, key, pivot, args):
         """AOT-stage the bucket's program on first use: trace → lower →
@@ -214,21 +216,17 @@ class MegaExecutor(StreamExecutor):
         fn = self._mega_fns.get((key, pivot))
         if fn is not None:
             return fn
-        jfn = _mega_kernel(*key, pivot, self.gemm_prec, self.pallas,
-                           self.mesh, self.pool_partition)
+        jfn = _mega_kernel(*key, pivot, self.gemm_prec, self.mesh,
+                           self.pool_partition)
         sds = tuple(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args)
         # program audit at AOT-stage time: a finding raises BEFORE the
         # XLA compile below ever runs (SLU_TPU_VERIFY_PROGRAMS=1)
         self._audit_program(self._census_site, self._census_label(key),
                             jfn, sds)
         t0 = time.perf_counter()
-        try:
-            traced = jfn.trace(*sds)          # jax >= 0.4.31
-            t1 = time.perf_counter()
-            lowered = traced.lower()
-        except AttributeError:                # older jax: fused stages
-            t1 = t0
-            lowered = jfn.lower(*sds)
+        traced = jfn.trace(*sds)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
         t2 = time.perf_counter()
         compiled = lowered.compile()
         t3 = time.perf_counter()

@@ -46,16 +46,46 @@ import numpy as np
 from superlu_dist_tpu.symbolic.symbfact import SymbolicFact
 
 
+#: TPU lane width: minor dimensions padded to a multiple of it reshape
+#: without a relayout, which the TPU compiler otherwise spends seconds
+#: to tens of seconds on per kernel at front sizes like 808 or 3736
+LANES = 128
+
+
+def lane_pad(n: int) -> int:
+    """``n`` rounded up to a whole number of lanes."""
+    return -(-int(n) // LANES) * LANES
+
+
+def pool_block(ub: int) -> int:
+    """Pool entries of one ub×ub Schur block: stored row-major with a
+    lane-padded row stride, so the (ub, lane_pad(ub)) block flattens
+    without a relayout (numeric/factor.pool_write)."""
+    return int(ub) * lane_pad(ub)
+
+
+def front_dims(m: int) -> tuple:
+    """(rows, cols) of one m×m front in the dump layout the factor
+    kernels assemble into (numeric/factor.group_step): at least m+1
+    each — row and column m are the dump the rel sentinel lands in —
+    with rows a multiple of 8 and cols a whole number of lanes, so the
+    flat batch reshapes to (batch, rows, cols) without a relayout."""
+    return -(-(int(m) + 1) // 8) * 8, lane_pad(int(m) + 1)
+
+
 @dataclasses.dataclass
 class ChildSet:
-    """Children of one group's fronts, bucketed by child U size.
+    """Children of one group's fronts, bucketed by child U size, with at
+    most one child per parent slot (a slot's k-th child is in its k-th
+    set of that bucket).
 
     The extend-add kernel gathers each child's padded ub×ub Schur block from
     the pool and scatter-adds it into the parent front at positions
     rel[c,i]·M + rel[c,j]; rel == M is the sentinel for padding (maps past
-    the front, dropped)."""
+    the front, dropped).  Within a set those targets are unique."""
 
-    ub: int                 # child U bucket (block is ub*ub in the pool)
+    ub: int                 # child U bucket (block is ub rows of stride
+                            # lane_pad(ub) in the pool — pool_block)
     child_off: np.ndarray   # (C,) pool offset of each child block
     child_slot: np.ndarray  # (C,) parent slot in this group
     rel: np.ndarray         # (C, ub) child row -> parent front position
@@ -127,10 +157,8 @@ class FactorPlan:
     def bytes_moved(self, itemsize: int = 8) -> int:
         """Irregular gather/scatter traffic of one factorization at this
         plan, in bytes — the data-movement honesty twin of the flop
-        padding factor (and the number the Pallas fused kernels exist to
-        shrink: they keep the front batch VMEM-resident instead of
-        round-tripping HBM per index).  Counted per moved element as its
-        accesses on the ``.at[]`` path:
+        padding factor.  Counted per moved element as its accesses on
+        the ``.at[]`` path:
 
         * A-entry assembly: one avals read + a front read-modify-write
           per structural entry (3 accesses);
@@ -192,6 +220,18 @@ class FactorPlan:
                 f"pool_size {self.pool_size} exceeds int32 index range; "
                 "enable jax_enable_x64 (the XSDK_INDEX_SIZE=64 analog) — "
                 "without it jax silently downcasts the int64 index maps")
+        # the front scatters index a whole group flat in the dump layout
+        # (front_dims), padded batch (< 2x the largest batch of the
+        # shape bucket) included
+        bmax: dict = {}
+        for g in self.groups:
+            bmax[(g.w, g.u)] = max(bmax.get((g.w, g.u), 0), g.batch)
+        flat = max((2 * b * int(np.prod(front_dims(w + u)))
+                    for (w, u), b in bmax.items()), default=0)
+        if flat >= 2 ** 31 and not jax.config.jax_enable_x64:
+            raise ValueError(
+                f"a front batch spans {flat} flat entries, past the int32 "
+                "index range; enable jax_enable_x64")
 
 
 # ---------------------------------------------------------------------------
@@ -612,7 +652,9 @@ def build_plan(sf: SymbolicFact, min_bucket: int = 8,
     flat_all = pi_all * group_m[sn_group[owner]] + pj_all
     slot_all = sn_slot[owner]
     g_of_entry = sn_group[owner]
-    by_group = np.argsort(g_of_entry, kind="stable")
+    # per group in (slot, front position) order: the front scatters
+    # declare their indices sorted (numeric/factor.group_step)
+    by_group = np.lexsort((flat_all, slot_all, g_of_entry))
     gbounds = np.searchsorted(g_of_entry[by_group],
                               np.arange(len(groups) + 1))
     ga_slot = [slot_all[by_group[gbounds[g]:gbounds[g + 1]]]
@@ -651,14 +693,14 @@ def build_plan(sf: SymbolicFact, min_bucket: int = 8,
         # free children blocks (they are fully consumed by this group)
         for ub, lst in grp_children[g].items():
             for (c, _) in lst:
-                free.setdefault(ub * ub, []).append(sn_off[c])
+                free.setdefault(pool_block(ub), []).append(sn_off[c])
         # allocate this group's blocks and register with parents
         for slot, s in enumerate(grp.sns):
             if us[s] == 0:
                 sn_off[s] = -1
                 continue
             ub = int(sn_U[s])
-            sn_off[s] = alloc(ub * ub)
+            sn_off[s] = alloc(pool_block(ub))
             p = int(sf.sn_parent[s])
             assert p >= 0
             gp = int(sn_group[p])
@@ -671,28 +713,44 @@ def build_plan(sf: SymbolicFact, min_bucket: int = 8,
     for g, grp in enumerate(groups):
         grp.a_slot, grp.a_flat, grp.a_src = ga_slot[g], ga_flat[g], ga_src[g]
         grp.off = np.where(us[grp.sns] > 0, sn_off[grp.sns], pool_size)
-        for ub, lst in sorted(grp_children[g].items()):
+        for ub, full in sorted(grp_children[g].items()):
             # child-id order, not dispatch order: the scatter-add rows a
             # parent front accumulates must be sequenced identically
             # under every schedule or the bitwise level/dataflow
             # equivalence guarantee breaks on ties
-            lst.sort()
-            C = len(lst)
-            cs = np.fromiter((c for c, _ in lst), dtype=np.int64, count=C)
-            ps = np.fromiter((p for _, p in lst), dtype=np.int64, count=C)
-            child_off = sn_off[cs]
-            child_slot = sn_slot[ps]
-            rel = np.full((C, ub), grp.m, dtype=np.int64)   # sentinel = M
-            # scatter each child's precomputed parent-positions into row k
-            kidx = np.repeat(np.arange(C), us[cs])
-            cidx = np.concatenate([np.arange(us[c]) for c in cs]) \
-                if C else np.empty(0, dtype=np.int64)
-            src = np.concatenate([rel_all[rows_ptr[c]:rows_ptr[c + 1]]
-                                  for c in cs]) \
-                if C else np.empty(0, dtype=np.int64)
-            rel[kidx, cidx] = src
-            grp.children.append(ChildSet(ub=ub, child_off=child_off,
-                                         child_slot=child_slot, rel=rel))
+            full.sort()
+            # one set per ROUND: the k-th child of every parent slot goes
+            # to set k, so no two children of a set share a slot and,
+            # ordered by slot, the set's scatter indices ascend
+            # (extend_add_set declares them sorted — a scatter-add the
+            # TPU compiler lowers in under a second instead of ~20 s).
+            # Each slot still receives its children in ascending id
+            # order, so the sums are unchanged bitwise.
+            seen: dict[int, int] = {}
+            rounds: list[list] = []
+            for c, p in full:
+                k = seen[p] = seen.get(p, -1) + 1
+                if k == len(rounds):
+                    rounds.append([])
+                rounds[k].append((c, p))
+            for lst in rounds:
+                # slot order within a set: its scatter indices ascend
+                lst.sort(key=lambda cp: sn_slot[cp[1]])
+                C = len(lst)
+                cs = np.fromiter((c for c, _ in lst), dtype=np.int64,
+                                 count=C)
+                ps = np.fromiter((p for _, p in lst), dtype=np.int64,
+                                 count=C)
+                rel = np.full((C, ub), grp.m, dtype=np.int64)  # sentinel M
+                # scatter each child's precomputed parent-positions into
+                # row k
+                kidx = np.repeat(np.arange(C), us[cs])
+                cidx = np.concatenate([np.arange(us[c]) for c in cs])
+                rel[kidx, cidx] = np.concatenate(
+                    [rel_all[rows_ptr[c]:rows_ptr[c + 1]] for c in cs])
+                grp.children.append(ChildSet(
+                    ub=ub, child_off=sn_off[cs], child_slot=sn_slot[ps],
+                    rel=rel))
         front_bytes += grp.batch * grp.m * grp.m
 
     # dependent-group critical path: the longest chain of groups where a

@@ -36,23 +36,6 @@ _SPLIT = jnp.float32(4097.0)      # 2^12 + 1 (Dekker split factor for f32)
 # for backends that honor them.
 _bar = jax.lax.optimization_barrier
 
-# Compat shim: older jaxlibs (< 0.5) ship optimization_barrier without a
-# vmap batching rule, and the df64 factorization vmaps the per-front
-# kernel over the batch axis (numeric/df64_factor.py).  The barrier is
-# shape-preserving and elementwise-transparent, so batching is identity
-# on the batch dims.
-try:
-    from jax.interpreters import batching as _batching
-    from jax._src.lax import lax as _lax_internal
-    _bar_p = _lax_internal.optimization_barrier_p
-    if _bar_p not in _batching.primitive_batchers:
-        def _bar_batching(args, dims, **params):
-            return _bar_p.bind(*args, **params), dims
-        _batching.primitive_batchers[_bar_p] = _bar_batching
-except Exception:                                # pragma: no cover
-    pass                                         # newer jax: rule exists
-
-
 def two_sum(a, b):
     """Exact sum: returns (s, err) with s + err == a + b exactly."""
     s = _bar(a + b)

@@ -13,7 +13,7 @@ TPU-native split: the analysis + factorization are single-address-space
 first assembled there, exactly like the reference's
 pdCompRow_loc_to_CompCol_global gather before serial preprocessing
 (pdgssvx.c:775).  The numeric work itself is SPMD-first: on a
-single-controller mesh the factorization is ONE shard_map program and
+single-controller mesh each factor group is one shard_map program and
 each solve sweep one more (parallel/spmd.py — panels block-cyclic over
 the flat device order, every extend-add/Schur/lsum exchange an
 in-program collective; factor.get_executor's auto rule picks it), and

@@ -1,20 +1,20 @@
-"""SPMD shard_map tier: one compiled program per factor / solve sweep.
+"""SPMD shard_map tier: one compiled program per factor group / solve sweep.
 
 The distributed execution model the reference's pdgstrf look-ahead
 pipeline (SRC/pdgstrf.c:624-697) exists to approximate by hand: instead
 of a per-rank host dispatch loop whose communication is host-mediated
 lockstep (parallel/treecomm.py — kept as the A/B reference and recovery
-fallback), the whole numeric factorization is ONE ``shard_map``-wrapped
-jitted program over a real ``jax.Mesh`` (axes registered in
-utils/meshreg.py), and each triangular-solve sweep bucket is one more.
-Panels are sharded BLOCK-CYCLICALLY over the flattened device order —
-slot j of a group lives on device ``j % nd`` (the reference's 2-D
-block-cyclic process-to-panel map, SURVEY.md §2.4) — and every
-extend-add / Schur / lsum exchange is an in-program ``all_gather`` /
-``psum`` leg derived from the FactorPlan dataflow schedule, so XLA sees
-the communication and can overlap it with the surrounding GEMMs: the
-look-ahead window becomes compiler-visible overlap instead of host
-lockstep (the ShyLU node-solver decomposition shape, arXiv:2506.05793).
+fallback), each (level, bucket) group of the numeric factorization is
+one ``shard_map``-wrapped jitted program over a real ``jax.Mesh`` (axes
+registered in utils/meshreg.py), dispatched asynchronously with the
+Schur pool resident and replicated on the devices, and each
+triangular-solve sweep bucket is one more program.  Panels are sharded
+BLOCK-CYCLICALLY over the flattened device order — slot j of a group
+lives on device ``j % nd`` (the reference's 2-D block-cyclic
+process-to-panel map, SURVEY.md §2.4) — and every extend-add / Schur /
+lsum exchange is an in-program ``all_gather`` / ``psum`` leg derived
+from the FactorPlan dataflow schedule, so XLA sees the communication
+and can overlap it with the surrounding GEMMs.
 
 Bitwise contract (the PR 5 pattern, gated by scripts/check_spmd_equiv.py
 and tests/test_spmd.py): L, U and X are bitwise-identical to the
@@ -37,8 +37,8 @@ lockstep/host path.  Two mechanisms carry it:
   exact scatter the single-device executors run is replayed redundantly
   on every device.  Identical scatter HLO on identical inputs ==
   identical bits, and the redundant copies keep the pool/x replicated
-  without any check_rep machinery (shard_map runs with
-  ``check_rep=False``; replication is by construction).
+  without any varying-axes machinery (shard_map runs with
+  ``check_vma=False``; replication is by construction).
 
 Padding sentinels follow the streamed executor's conventions
 (numeric/stream.py): OOB scatter slots == local batch (dropped), OOB
@@ -111,30 +111,69 @@ def _partition_rows(owner: np.ndarray, nd: int, pads: list, cols: list):
     return c_max, out
 
 
+def _group_program(mesh, dims, ubs, pivot, gemm_prec, specs):
+    """One group's step as a jitted shard_map program: each device
+    assembles and factors its block-cyclic slot partition, then the
+    panels and Schur blocks are all-gathered, un-permuted to slot order,
+    and the pool write is replayed in full order on every device."""
+    from jax.sharding import PartitionSpec as P
+    from superlu_dist_tpu.numeric.factor import group_step, pool_write
+    axes = tuple(mesh.axis_names)
+    b_loc, m, w, u = dims
+
+    def step(avals, pool, thresh, a_slot, a_flat, a_src, ws_l, off_full, g,
+             *child):
+        children = [(ub, child[3 * i], child[3 * i + 1], child[3 * i + 2])
+                    for i, ub in enumerate(ubs)]
+        # off=None: write_back=False never reaches the pool scatter — the
+        # replay below IS the pool write
+        (lp_l, up_l), schur, t = group_step(
+            (b_loc, m, w, u), avals, pool, thresh, a_slot, a_flat, a_src,
+            ws_l, None, children, pivot=pivot, gemm_prec=gemm_prec,
+            write_back=False)
+        lp = jnp.take(jax.lax.all_gather(lp_l, axes, axis=0, tiled=True),
+                      g, axis=0)
+        up = jnp.take(jax.lax.all_gather(up_l, axes, axis=0, tiled=True),
+                      g, axis=0)
+        if u > 0:
+            sv = jnp.take(jax.lax.all_gather(schur, axes, axis=0,
+                                             tiled=True), g, axis=0)
+            pool = pool_write(pool, off_full, sv)
+        return (lp, up), pool, jax.lax.psum(t, axes)
+
+    rep = P()
+    smapped = jax.shard_map(step, mesh=mesh, in_specs=(rep, rep, rep) + specs,
+                            out_specs=rep, check_vma=False)
+    # the pool is threaded linearly through the groups: donate it
+    return jax.jit(smapped, donate_argnums=(1,))
+
+
 class SpmdFactorExecutor:
-    """The whole numeric factorization as ONE shard_map program.
+    """The numeric factorization as one shard_map program per group.
 
     Per (level, bucket) group, each device assembles and factors only
     its block-cyclic slot partition (``group_step`` with
     ``write_back=False`` — identical per-slot arithmetic to every other
     executor), then the panels and Schur values are all-gathered,
     un-permuted to slot order, and the pool write is replayed in full
-    order on every device.  The program count is 1 per factorization
-    regardless of n (the compile-budget discipline), and the
-    inter-group extend-add dataflow is visible to XLA as
-    gather-then-compute it can overlap — the look-ahead window as
-    compiler scheduling.
+    order on every device (``_group_program``).  The pool stays
+    replicated on the devices between programs, and the programs are
+    compiled ahead of the first factorization in parallel threads
+    (stream.compile_all): a whole-factorization program at n=110,592
+    did not finish compiling for a 2x2 v5e mesh in minutes, while its
+    89 group programs build independently.
 
-    Same call surface as the fused executor: ``fn(avals, thresh) ->
-    (fronts_tuple, tiny)``; no per-group boundaries, so checkpointing
-    forces the streamed executor (numeric_factorize).
+    Same call surface as the other executors: ``fn(avals, thresh) ->
+    (fronts_tuple, tiny)``.  Checkpointing forces the streamed executor
+    (numeric_factorize).
     """
 
-    def __init__(self, plan, dtype="float64", mesh=None, gemm_prec=None,
-                 pallas=None):
+    _census_site = "spmd.factor"
+
+    def __init__(self, plan, dtype="float64", mesh=None, gemm_prec=None):
         if mesh is None:
             raise ValueError("SpmdFactorExecutor needs a mesh")
-        from superlu_dist_tpu.numeric.pallas_kernels import pallas_mode
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from superlu_dist_tpu.ops.dense import gemm_precision, pivot_kernel
         from superlu_dist_tpu.symbolic.symbfact import _front_flops
         plan.check_index_width()
@@ -142,22 +181,19 @@ class SpmdFactorExecutor:
         self.mesh = mesh
         self.dtype = jnp.dtype(dtype)
         self._axes = tuple(mesh.axis_names)
-        self.nd = int(np.prod(mesh.devices.shape))
+        self.nd = nd = int(np.prod(mesh.devices.shape))
         # env knobs resolved HERE, in the uncached constructor, and baked
-        # into the one compiled program (slulint SLU102/SLU105); Pallas
-        # rides through per-shard (interpret on CPU meshes, native on TPU)
+        # into the compiled programs (slulint SLU102/SLU105)
         self.gemm_prec = gemm_precision(gemm_prec)
-        self.pallas = pallas_mode(pallas)
         self._pivot = pivot_kernel()
-        self._built = False
-        nd = self.nd
         n_avals = len(plan.pattern_indices)
-
-        meta = []          # per group: (B, B_loc, m, w, u, child ubs)
-        flat = []          # program inputs, device-major repacked
-        specs = []         # matching PartitionSpecs (built programmatically)
-        from jax.sharding import PartitionSpec as P
         sh, rep = P(self._axes), P()
+        self._rep = NamedSharding(mesh, rep)
+        # per group: (program key, host index arrays) — placed on the
+        # mesh at the first call (``_place``), so a described mesh with
+        # no devices attached can still lower and compile the programs
+        self._steps = []
+        self._programs = {}     # program key -> jitted shard_map program
         executed = 0.0
         for grp in plan.groups:
             b = grp.batch
@@ -173,10 +209,8 @@ class SpmdFactorExecutor:
             ws = np.asarray(grp.ws)
             srcc = np.minimum(src, max(b - 1, 0))
             ws_s = np.where(valid, ws[srcc], 0).astype(ws.dtype)
-            flat += [jnp.asarray(as_s), jnp.asarray(af_s),
-                     jnp.asarray(asrc_s), jnp.asarray(ws_s),
-                     jnp.asarray(np.asarray(grp.off)), jnp.asarray(g)]
-            specs += [sh, sh, sh, sh, rep, rep]
+            arrs = [(as_s, sh), (af_s, sh), (asrc_s, sh), (ws_s, sh),
+                    (np.asarray(grp.off), rep), (g, rep)]
             ubs = []
             for cs in grp.children:
                 child_slot = np.asarray(cs.child_slot)
@@ -185,98 +219,104 @@ class SpmdFactorExecutor:
                     [plan.pool_size, b_loc, grp.m],
                     [np.asarray(cs.child_off), child_slot // nd,
                      np.asarray(cs.rel)])
-                flat += [jnp.asarray(co_s), jnp.asarray(cs_s),
-                         jnp.asarray(rel_s)]
-                specs += [sh, sh, sh]
+                arrs += [(co_s, sh), (cs_s, sh), (rel_s, sh)]
                 ubs.append(cs.ub)
-            meta.append((b, b_loc, grp.m, grp.w, grp.u, tuple(ubs)))
-        self._flat = tuple(flat)
+            args = tuple(np.asarray(x) for x, _ in arrs)
+            specs = tuple(spec for _, spec in arrs)
+            dims = (b_loc, grp.m, grp.w, grp.u)
+            key = (dims, tuple(ubs), specs,
+                   tuple((x.shape, x.dtype) for x in args))
+            if key not in self._programs:
+                self._programs[key] = _group_program(
+                    mesh, dims, tuple(ubs), self._pivot, self.gemm_prec,
+                    specs)
+            self._steps.append((key, args))
+        self._placed = None     # per group: arguments on the mesh
+        # XLA:CPU runs every device's share of a program on one shared
+        # thread pool, and a collective holds its thread until all
+        # devices arrive: with two group programs in flight, devices
+        # waiting in the later one can take every thread from a device
+        # still in the earlier one (a rendezvous deadlock).  There, each
+        # group finishes before the next is issued.
+        self._serial = mesh.devices.flat[0].platform == "cpu"
+        self._compiled = {}     # program key -> compiled executable
         self.executed_flops = float(executed)
-
-        dtype_ = self.dtype
-        axes = self._axes
-        pivot, gp, pal = self._pivot, self.gemm_prec, self.pallas
-        pool_size = plan.pool_size
-        from superlu_dist_tpu.numeric.factor import group_step
-
-        def fn(avals, thresh, *args):
-            avals = avals.astype(dtype_)
-            # every device holds the full pool and replays every write
-            # in full order — replicated by construction, and the
-            # extend-add gathers need no communication at all
-            pool = jnp.zeros(pool_size, dtype=dtype_)
-            fronts = []
-            tiny = jnp.zeros((), jnp.int32)
-            i = 0
-            for (b, b_loc, m, w, u, ubs) in meta:
-                a_slot, a_flat, a_src, ws_l, off_full, g = args[i:i + 6]
-                i += 6
-                children = []
-                for ub in ubs:
-                    children.append((ub, args[i], args[i + 1], args[i + 2]))
-                    i += 3
-                # off=None: write_back=False never reaches the pool
-                # scatter — the replay below IS the pool write
-                packed, schur, t = group_step(
-                    (b_loc, m, w, u), avals, pool, thresh, a_slot,
-                    a_flat, a_src, ws_l, None, children, pivot=pivot,
-                    gemm_prec=gp, pallas=pal, write_back=False)
-                lp_l, up_l = packed
-                lp = jnp.take(jax.lax.all_gather(lp_l, axes, axis=0,
-                                                 tiled=True), g, axis=0)
-                up = jnp.take(jax.lax.all_gather(up_l, axes, axis=0,
-                                                 tiled=True), g, axis=0)
-                if u > 0:
-                    sv = jnp.take(jax.lax.all_gather(schur, axes, axis=0,
-                                                     tiled=True), g, axis=0)
-                    dst = off_full[:, None] + jnp.arange(u * u)
-                    pool = pool.at[dst].set(sv, mode="drop")
-                fronts.append((lp, up))
-                tiny = tiny + t
-            return tuple(fronts), jax.lax.psum(tiny, axes)
-
-        from jax.experimental.shard_map import shard_map
-        smapped = shard_map(fn, mesh=mesh,
-                            in_specs=(rep, rep) + tuple(specs),
-                            out_specs=rep, check_rep=False)
-        self._jfn = jax.jit(smapped)
-        self._label = (f"spmd g{len(plan.groups)} nd{nd} "
-                       f"{str(self.dtype)} {self.gemm_prec}")
-        # fused-executor telemetry surface (bench.py / drivers read these)
+        # executor telemetry surface (bench.py / drivers read these)
         self.offload = 0.0
-        self.granularity = "program"
-        self.n_kernels = 1
+        self.granularity = "group"
+        self.n_kernels = len(self._programs)
         self.last_profile = None
         self.last_dispatch_seconds = 0.0
 
+    def _label(self, key) -> str:
+        (b, m, w, u) = key[0]
+        return (f"spmd nd{self.nd} b{b} m{m} w{w} u{u} {self.dtype} "
+                f"{self.gemm_prec}")
+
+    def _place(self) -> list:
+        if self._placed is None:
+            from jax.sharding import NamedSharding
+            self._placed = [
+                tuple(jax.device_put(x, NamedSharding(self.mesh, spec))
+                      for x, spec in zip(args, key[2]))
+                for key, args in self._steps]
+        return self._placed
+
+    def _build(self, avals, pool, thresh) -> None:
+        """Compile every group program not yet built, in parallel; each
+        build lands in the compile census and, when armed, the runtime
+        program auditors."""
+        from superlu_dist_tpu.numeric.stream import compile_all
+        from superlu_dist_tpu.utils.programaudit import maybe_audit
+        todo = {}
+        for (key, _), args in zip(self._steps, self._place()):
+            if key not in self._compiled and key not in todo:
+                todo[key] = (avals, pool, thresh, *args)
+        if not todo:
+            return
+        keys, lowered, starts = list(todo), [], []
+        for key in keys:
+            fn, args = self._programs[key], todo[key]
+            maybe_audit(self._census_site, self._label(key), fn, args,
+                        dead=(1,), mesh_axes=self._axes)
+            starts.append(time.perf_counter())
+            lowered.append(fn.lower(*args))
+        for i, exe, secs in compile_all(lowered,
+                                        label=lambda i: self._label(keys[i])):
+            self._compiled[keys[i]] = exe
+            COMPILE_STATS.record(self._census_site, self._label(keys[i]),
+                                 starts[i], secs, n_args=len(todo[keys[i]]))
+
     def __call__(self, avals, thresh):
         tracer = get_tracer()
-        cold = not self._built
-        if cold:
-            from superlu_dist_tpu.utils.programaudit import maybe_audit
-            maybe_audit("spmd.factor", self._label, self._jfn,
-                        (avals, thresh, *self._flat),
-                        mesh_axes=self._axes)
+        avals = jax.device_put(jnp.asarray(avals, self.dtype), self._rep)
+        thresh = jax.device_put(thresh, self._rep)
+        pool = jnp.zeros(self.plan.pool_size, self.dtype, device=self._rep)
+        self._build(avals, pool, thresh)
+        fronts = []
+        tiny = jnp.zeros((), jnp.int32)
         t0 = time.perf_counter()
-        out = self._jfn(avals, thresh, *self._flat)
+        for (key, _), args in zip(self._steps, self._place()):
+            (lp, up), pool, t = self._compiled[key](avals, pool, thresh,
+                                                    *args)
+            if self._serial:
+                jax.block_until_ready(pool)
+            fronts.append((lp, up))
+            tiny = tiny + t
         t_issue = time.perf_counter() - t0
         self.last_dispatch_seconds = t_issue
-        if cold:
-            self._built = True
-            COMPILE_STATS.record("spmd.factor", self._label, t0, t_issue,
-                                 n_args=2)
         if tracer.enabled:
             tracer.complete("issue spmd", "dispatch", t0, t_issue,
                             groups=len(self.plan.groups), n_devices=self.nd)
             if tracer.profiling:
-                jax.block_until_ready(out[0])
+                jax.block_until_ready(fronts)
                 tracer.complete("factor-spmd", "kernel", t0,
                                 time.perf_counter() - t0,
                                 n_groups=len(self.plan.groups),
                                 aggregate=True,
                                 executed_flops=self.executed_flops,
                                 structural_flops=float(self.plan.flops))
-        return out
+        return tuple(fronts), tiny
 
 
 from superlu_dist_tpu.solve.device import DeviceSolver, _trsm
@@ -366,7 +406,6 @@ class SpmdSolver(DeviceSolver):
     def _spmd_program(self, conj=None):
         """Build one fwd+bwd shard_map program (notrans when conj is
         None, else the transpose pair with optional conjugation)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         meta = self._spmd_meta
         axes = self._axes
@@ -439,9 +478,9 @@ class SpmdSolver(DeviceSolver):
             return x
 
         rep = P()
-        smapped = shard_map(sweep, mesh=self.spmd_mesh,
-                            in_specs=(rep, rep) + self._spmd_specs,
-                            out_specs=rep, check_rep=False)
+        smapped = jax.shard_map(sweep, mesh=self.spmd_mesh,
+                                in_specs=(rep, rep) + self._spmd_specs,
+                                out_specs=rep, check_vma=False)
         return jax.jit(smapped, donate_argnums=(0, 1))
 
     def _spmd_fns(self, kb, conj=None):

@@ -46,7 +46,7 @@ from superlu_dist_tpu.utils.errors import (
     CheckpointCorruptError, CheckpointError, CheckpointVersionError)
 
 FORMAT = "slu-tpu-persist"
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2   # 2: sorted A-entry maps, one child per slot per set
 MANIFEST = "MANIFEST.json"
 
 

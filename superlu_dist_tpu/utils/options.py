@@ -82,14 +82,6 @@ def _register_all() -> None:
       "skeleton at the next tier when delivered accuracy misses the "
       "gate (docs/PERFORMANCE.md throughput ladder)", group="numeric",
       choices=("", "bf16", "default", "f32", "highest"))
-    r("SLU_TPU_PALLAS", "str", "auto",
-      "Pallas fused gather/scatter kernels for the extend-add and "
-      "A-assembly hot spots (numeric/pallas_kernels.py): auto = on "
-      "when a TPU backend is present, 1/on = force (interprets on "
-      "CPU), interpret = force interpreter mode, 0/off = the .at[] "
-      "lowering.  Both paths are bitwise-identical "
-      "(tests/test_precision_ladder.py pins it)", group="numeric",
-      choices=("auto", "0", "1", "on", "off", "interpret"))
     r("SLU_TPU_PIVOT_KERNEL", "str", "blocked",
       "panel factorization kernel", group="numeric",
       choices=("blocked", "recursive"))
@@ -140,7 +132,7 @@ def _register_all() -> None:
                                 "spmd"))
     r("SLU_TPU_SPMD", "str", "auto",
       "shard_map SPMD tier gate (parallel/spmd.py): auto/empty = on "
-      "for single-process meshes (one compiled program per factor and "
+      "for single-process meshes (one compiled program per factor group and "
       "per solve-sweep bucket, bitwise-identical to the lockstep "
       "path), 0/off = keep the GSPMD stream/fused tiers, anything "
       "else = force on", group="numeric",
@@ -396,18 +388,15 @@ def _register_all() -> None:
       "failure-domain chaos-injection spec (testing/chaos.py, e.g. "
       "'kill_group=5', 'nan_supernode=3', 'kill_refactor@step=0', "
       "'poison_values=2'); empty = off", group="test")
-    r("SLU_TPU_SKIP_PROBE", "flag", False,
-      "__graft_entry__: skip the accelerator probe", group="test")
     r("SLU_TPU_DRYRUN_BIG", "str", "1",
       "__graft_entry__: include the n=1e5 pool-partition dryrun phase",
-      group="test")
-    r("SLU_TPU_ORIG_PLATFORMS", "str", "",
-      "test harness stash of the session's original JAX_PLATFORMS pin",
       group="test")
     # --- external (read, not owned, by this project) -----------------------
     for name, help_ in (
             ("JAX_PLATFORMS", "jax backend selection"),
             ("XLA_FLAGS", "XLA compiler/runtime flags"),
+            ("LIBTPU_INIT_ARGS", "TPU runtime/compiler flags (the package "
+             "appends its fiber stack size on import)"),
             ("JAX_ENABLE_X64", "jax 64-bit mode"),
             ("JAX_DEBUG_NANS", "raise on NaN production in jitted code"),
             ("PYTHONPATH", "module search path for subprocesses")):
@@ -415,11 +404,6 @@ def _register_all() -> None:
     # --- bench.py ----------------------------------------------------------
     r("BENCH_DEADLINE_S", "float", 1350.0,
       "bench watchdog deadline (seconds)", group="bench")
-    for name, help_ in (
-            ("BENCH_NO_PROBE", "skip the TPU probe subprocess"),
-            ("BENCH_REQUIRE_TPU", "fail instead of falling back to CPU"),
-            ("BENCH_FORCE_CPU", "pin the bench to the CPU backend")):
-        r(name, "flag", False, help_, group="bench")
     for name, kind, default, help_ in (
             ("BENCH_NX", "int", 48, "Poisson grid edge (n = NX^3)"),
             ("BENCH_REPS", "int", 3, "timed repetitions"),
@@ -865,14 +849,11 @@ def print_options(o: Options) -> str:
 
 
 def default_factor_dtype() -> str:
-    """float32 on TPU (no fp64 MXU), float64 elsewhere."""
-    try:
-        import jax
-        platform = jax.default_backend()
-    except Exception:  # pragma: no cover - jax always present in practice
-        platform = "cpu"
-    if platform == "cpu" and os.environ.get("JAX_ENABLE_X64", "").lower() not in ("0", "false"):
-        import jax
-        if jax.config.read("jax_enable_x64"):
-            return "float64"
+    """float32 on TPU (no fp64 MXU), float64 on the CPU with x64."""
+    import jax
+    if (jax.default_backend() == "cpu"
+            and os.environ.get("JAX_ENABLE_X64", "").lower()
+            not in ("0", "false")
+            and jax.config.read("jax_enable_x64")):
+        return "float64"
     return "float32"

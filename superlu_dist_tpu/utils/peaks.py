@@ -9,12 +9,14 @@ denominator instead:
 * ``SLU_TPU_PEAK_GFLOPS`` (registered knob) overrides everything — the
   operator's calibrated figure wins;
 * TPU backends look up a per-device-kind, per-GEMM-tier table
-  (``TPU_PEAK_GFLOPS`` — vendor bf16 figures; the ``f32``/``highest``
-  tiers divide by the 3-/6-pass MXU cost, the ``default``/``bf16``
-  tiers run at the native single-pass rate);
-* the CPU backend (and anything unknown) CALIBRATES: one cached
-  micro-GEMM per tier, timed at steady state — a measured machine-local
-  peak instead of a borrowed constant.
+  (``TPU_PEAK_GFLOPS`` — vendor bf16 figures keyed by the exact
+  ``device_kind`` jax reports; the ``f32``/``highest`` tiers divide by
+  the 3-/6-pass MXU cost, the ``default``/``bf16`` tiers run at the
+  native single-pass rate).  A kind missing from the table raises —
+  never a borrowed figure;
+* the CPU backend CALIBRATES: one cached micro-GEMM per tier, timed at
+  steady state — a measured machine-local peak instead of a borrowed
+  constant.
 
 Every consumer reports the peak's provenance alongside the percentage
 (``peak_source``), so an MFU number can always be traced to the
@@ -29,38 +31,33 @@ import functools
 
 from superlu_dist_tpu.utils.options import env_float
 
-#: vendor peak dense-matmul throughput in GFLOP/s per TPU device kind
-#: (matched by substring against jax's ``device_kind``, first hit wins)
-#: at the bf16 native rate; reduced-precision tiers derive from it via
-#: the MXU pass counts (default/bf16 = 1 pass, f32 = 3, highest = 6).
+#: vendor peak dense-matmul throughput in GFLOP/s per TPU chip at the
+#: bf16 native rate, keyed by jax's ``device_kind`` exactly as a device
+#: reports it; reduced-precision tiers derive from it via the MXU pass
+#: counts (default/bf16 = 1 pass, f32 = 3, highest = 6).  Source: Google
+#: Cloud TPU documentation, one page per generation ("TPU v5e": 197
+#: TFLOP/s bf16; "TPU v5p": 459; "TPU v4": 275; "TPU v6e": 918).
 TPU_PEAK_GFLOPS = {
-    "v6e": 918_000.0,
-    "v6": 918_000.0,
-    "v5p": 459_000.0,
-    "v5e": 197_000.0,
-    "v5litepod": 197_000.0,
-    "v4": 275_000.0,
-    "v3": 123_000.0,
-    "v2": 45_000.0,
-    # unrecognized TPU kinds fall back to the v5e figure — labeled as
-    # such in the source string so nobody mistakes it for a measurement
-    "tpu": 197_000.0,
+    "TPU v5 lite": 197_000.0,     # v5e
+    "TPU v5": 459_000.0,          # v5p
+    "TPU v4": 275_000.0,
+    "TPU v6 lite": 918_000.0,     # v6e
 }
 
 #: MXU passes per GEMM tier (ops/dense.GEMM_PREC_LADDER semantics)
 TIER_PASSES = {"bf16": 1, "default": 1, "f32": 3, "highest": 6}
 
 
-def table_peak_gflops(device_kind: str, gemm_precision: str) -> float | None:
-    """Tabulated TPU peak for one device kind + GEMM tier, or None when
-    the kind matches nothing.  Pure table lookup — no jax import — for
-    offline row post-processing (scripts/mfu_report.py)."""
-    kind = (device_kind or "").lower()
-    passes = TIER_PASSES.get(gemm_precision, 6)
-    for key, bf16_peak in TPU_PEAK_GFLOPS.items():
-        if key in kind:
-            return bf16_peak / passes
-    return None
+def table_peak_gflops(device_kind: str, gemm_precision: str) -> float:
+    """Tabulated TPU peak for one device kind + GEMM tier.  Pure table
+    lookup — no jax import — for offline row post-processing
+    (scripts/mfu_report.py).  A kind the table does not hold raises
+    KeyError."""
+    if device_kind not in TPU_PEAK_GFLOPS:
+        raise KeyError(f"no peak for device_kind {device_kind!r}: the "
+                       f"table (utils/peaks.py) holds "
+                       f"{sorted(TPU_PEAK_GFLOPS)}")
+    return TPU_PEAK_GFLOPS[device_kind] / TIER_PASSES.get(gemm_precision, 6)
 
 
 @functools.lru_cache(maxsize=None)
@@ -93,7 +90,8 @@ def detect_peak_gflops(gemm_precision: str,
     """Resolve the MFU denominator for this process: ``(gflops,
     source)`` where source names the provenance ("env", "table:<kind>",
     or "measured:<backend>").  ``SLU_TPU_PEAK_GFLOPS`` wins when set;
-    TPU backends read the vendor table; everything else calibrates."""
+    TPU backends read the vendor table (an unknown kind raises);
+    everything else calibrates."""
     override = env_float("SLU_TPU_PEAK_GFLOPS")
     if override > 0:
         return float(override), "env"
@@ -101,13 +99,8 @@ def detect_peak_gflops(gemm_precision: str,
     if backend is None:
         backend = jax.default_backend()
     if backend == "tpu":
-        try:
-            kind = jax.devices()[0].device_kind
-        except Exception:
-            kind = "tpu"
-        peak = table_peak_gflops(kind, gemm_precision)
-        if peak is not None:
-            return peak, f"table:{kind}"
+        kind = jax.devices()[0].device_kind
+        return table_peak_gflops(kind, gemm_precision), f"table:{kind}"
     return _calibrate_gflops(gemm_precision), f"measured:{backend}"
 
 

@@ -158,6 +158,8 @@ class Stats:
     for_lu_bytes: int = 0         # dQuerySpace_dist analog: packed L+U
     pool_bytes: int = 0           # transient Schur update pool
     solve_report: object = None   # SolveReport of the last driver solve
+    ir_residual: str = ""         # "device" | "host": where the last
+                                  # refinement's residual SpMVs ran
     comm: dict = field(default_factory=dict)   # CommStats.totals() snapshot
     sched: dict = field(default_factory=dict)  # FactorPlan.schedule_stats()
                                   # of the last factorization (dispatch

@@ -151,3 +151,11 @@ RESID_GATE_TIGHT = tol("float64", 2 ** 19,
 #: dlacon.f:130 uses a tiny relative slack; was the hand-typed 1e-12)
 ONENORMEST_SLACK = tol("float64", 2 ** 12,
                        "onenormest subgradient convergence slack")
+
+#: SPMD (four chips) vs one-chip f32 factors of the same matrix on the
+#: chip: different reduction orders, so the unrefined solves agree only
+#: to the f32 class — each carries ~κ·eps(f32) error on the κ ≈ 1e3
+#: poisson3d(48) system chip_smoke.py runs (not bitwise: the bitwise
+#: SPMD contract was derived on XLA:CPU, docs/PERFORMANCE.md)
+SPMD_VS_ONE_CHIP_F32 = tol("float32", 2 ** 12,
+                           "SPMD vs one-chip f32 factors, unrefined solve")

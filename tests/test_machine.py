@@ -61,99 +61,62 @@ def test_stats_timer_accumulates():
     assert s.utime["FACT"] >= 0.009
 
 
-# ---- compile-cache machine scoping (round-4 poisoned-cache class) --------
+# ---- compile-cache policy (utils/jaxcache.py) ----------------------------
 
-def test_machine_fingerprint_stable_and_scoped(tmp_path, monkeypatch):
-    """The persistent compile cache must be keyed by a machine/toolchain
-    fingerprint: XLA:CPU AOT entries written on a different machine hang
-    multi-device runs (cpu_aot 'machine features don't match' / SIGILL
-    class).  Scoping the directory makes foreign entries unreachable by
-    construction — a foreign box's entries live under a different
-    fingerprint and are never opened here."""
-    import superlu_dist_tpu.utils.jaxcache as jc
-
-    fp = jc.machine_fingerprint()
-    assert fp == jc.machine_fingerprint()          # memoized + stable
-    assert len(fp) == 10 and all(c in "0123456789abcdef" for c in fp)
-
-    d = jc.cache_dir_for_machine(str(tmp_path))
-    assert d == str(tmp_path / f"jax-mach-{fp}")
-
-    # simulated foreign-entry injection: entries under another machine's
-    # fingerprint directory must not be visible from this machine's dir
-    foreign = tmp_path / "jax-mach-deadbeef00"
-    foreign.mkdir()
-    (foreign / "xla_aot_entry").write_bytes(b"\x90" * 64)
-    import os
-    assert not os.path.exists(d) or "xla_aot_entry" not in os.listdir(d)
-
-    # the fingerprint reacts to the inputs it hashes (cpuinfo flags):
-    # recompute with the memo cleared and a faked cpuinfo
-    monkeypatch.setattr(jc, "_FP_CACHE", None)
-    real_open = open
-
-    def fake_open(path, *a, **k):
-        if path == "/proc/cpuinfo":
-            import io
-            return io.StringIO("model name: other-cpu\nflags: none\n")
-        return real_open(path, *a, **k)
-
-    monkeypatch.setattr("builtins.open", fake_open)
-    fp2 = jc.machine_fingerprint()
-    monkeypatch.setattr(jc, "_FP_CACHE", None)
-    assert fp2 != fp
-
-
-def test_cache_dir_host_feature_stamp(tmp_path):
-    """enable_compile_cache stamps the directory with the raw host
-    features and refuses to reuse a directory stamped by a different
-    host: a mismatch re-scopes to a feature-exact subdirectory (the
-    poisoned entries are never opened) and bumps isa_mismatch_count —
-    the counter the bench asserts stays 0 (BENCH_r05 'machine features
-    don't match ... SIGILL' tail)."""
-    import os
+def test_compile_cache_env_dir_is_the_only_cache(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache:
+    enable_compile_cache configures no other, even when handed one."""
+    import jax
 
     import superlu_dist_tpu.utils.jaxcache as jc
 
-    prior = jc.current_cache_dir()
-    mine = str(tmp_path / "cache")
+    env_dir = str(tmp_path / "from-env")
+    prior = (jc.current_cache_dir(), jc.cache_enabled())
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    jax.config.update("jax_compilation_cache_dir", env_dir)
     try:
-        base = jc.isa_mismatch_count()
-        jc.enable_compile_cache(mine)
-        stamp = os.path.join(mine, ".host_features")
-        assert os.path.exists(stamp)
-        assert open(stamp).read() == jc.host_features()
-        # matching stamp: same dir, no mismatch recorded
-        jc.enable_compile_cache(mine)
-        assert jc.current_cache_dir() == mine
-        assert jc.isa_mismatch_count() == base
-        # foreign stamp: re-scope to a feature-exact subdir, count it
-        with open(stamp, "w") as fh:
-            fh.write("some-other-host|other-flags")
-        jc.enable_compile_cache(mine)
-        used = jc.current_cache_dir()
-        assert used != mine and used.startswith(mine)
-        assert os.path.basename(used).startswith("isa-")
-        assert open(os.path.join(used, ".host_features")).read() \
-            == jc.host_features()
-        assert jc.isa_mismatch_count() == base + 1
+        assert jc.cache_dir() == env_dir
+        assert jc.enable_compile_cache() == env_dir
+        assert jc.enable_compile_cache(str(tmp_path / "other")) == env_dir
+        assert jc.current_cache_dir() == env_dir
+        assert not (tmp_path / "other").exists()
+        assert jc.bucket_warm_marker("d").startswith(env_dir)
     finally:
-        if prior:
-            jc.enable_compile_cache(prior)
-        else:
-            jc.disable_compile_cache()
+        jax.config.update("jax_compilation_cache_dir", prior[0])
+        jax.config.update("jax_enable_compilation_cache", prior[1])
 
 
-def test_dryrun_throwaway_cache_never_outlives_its_directory(monkeypatch,
-                                                             tmp_path):
-    """dryrun_multichip uses a deliberately throwaway compile cache; on
-    exit it must restore the caller's policy EXACTLY.  With a prior
-    cache configured, that cache comes back; with none, the cache must
-    end up DISABLED — the historical bug left the rmtree'd temp dir
-    active, so a later same-process compile silently resurrected it and
-    wrote/reloaded XLA:CPU AOT entries (ADVICE round 5)."""
+def test_compile_cache_defaults_to_checkout_dir(monkeypatch):
+    """Without the variable, the cache is the fixed .cache/jax inside
+    the checkout — no machine-scoped or re-scoped subdirectory."""
+    import os
+
+    import jax
+
+    import superlu_dist_tpu.utils.jaxcache as jc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".cache", "jax")
+    prior = (jc.current_cache_dir(), jc.cache_enabled())
+    try:
+        assert jc.cache_dir() == want
+        assert jc.enable_compile_cache() == want
+        assert jc.current_cache_dir() == want
+        assert jc.cache_enabled()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior[0])
+        jax.config.update("jax_enable_compilation_cache", prior[1])
+
+
+def test_dryrun_throwaway_cache_never_outlives_its_directory(monkeypatch):
+    """dryrun_multichip runs with the persistent compile cache OFF
+    (XLA:CPU entries of collective programs wedge on reload) and hands
+    the caller's setting back unchanged afterwards, on or off."""
     import importlib.util
     import os
+
+    import jax
 
     import superlu_dist_tpu.utils.jaxcache as jc
 
@@ -162,24 +125,18 @@ def test_dryrun_throwaway_cache_never_outlives_its_directory(monkeypatch,
     spec = importlib.util.spec_from_file_location("__graft_entry__", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    seen = []
     # the cache policy is what's under test, not the dryrun body
-    monkeypatch.setattr(mod, "_dryrun_body", lambda n: None)
+    monkeypatch.setattr(mod, "_dryrun_body",
+                        lambda n: seen.append(jc.cache_enabled()))
 
-    prior = jc.current_cache_dir()
+    prior_dir, prior_on = jc.current_cache_dir(), jc.cache_enabled()
     try:
-        # case 1: no prior cache -> disabled afterwards (and NOT the
-        # temp dir, which no longer exists)
-        jc.disable_compile_cache()
-        mod.dryrun_multichip(2)
-        after = jc.current_cache_dir()
-        assert not after, after
-        # case 2: a prior cache -> restored verbatim
-        mine = str(tmp_path / "prior-cache")
-        jc.enable_compile_cache(mine)
-        mod.dryrun_multichip(2)
-        assert jc.current_cache_dir() == mine
+        for caller_on in (True, False):
+            jax.config.update("jax_enable_compilation_cache", caller_on)
+            mod.dryrun_multichip(2)
+            assert jc.cache_enabled() is caller_on
+            assert jc.current_cache_dir() == prior_dir
+        assert seen == [False, False]
     finally:
-        if prior:
-            jc.enable_compile_cache(prior)
-        else:
-            jc.disable_compile_cache()
+        jax.config.update("jax_enable_compilation_cache", prior_on)

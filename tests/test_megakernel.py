@@ -348,6 +348,8 @@ def test_warm_start_second_run_compiles_nothing(tmp_path):
     child.write_text(_WARM_CHILD)
     cache = str(tmp_path / "jaxcache")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    # the pair must share the test's own cache, not one set outside
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     rows = []
     for _ in range(2):
         r = subprocess.run([sys.executable, str(child), cache], env=env,
@@ -442,7 +444,7 @@ def test_executors_announce_their_key_sets():
 
 def test_bench_row_carries_mega_census_fields(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_NX="6",
-               BENCH_REPS="1", BENCH_NO_PROBE="1", BENCH_FORCE_CPU="1",
+               BENCH_REPS="1",
                BENCH_DEADLINE_S="420", BENCH_GRANULARITY="mega",
                BENCH_SOLVE_NRHS="")
     env.pop("SLU_TPU_TRACE", None)

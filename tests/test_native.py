@@ -194,3 +194,32 @@ def test_mlnd_fill_quality_vs_scipy_colamd():
                   options=dict(SymmetricMode=False))
     assert sf.nnz_L <= 2.0 * lu.L.nnz, (sf.nnz_L, lu.L.nnz)
 
+
+
+def test_library_named_by_source_hash():
+    """The loaded library is the one built from the committed source:
+    its file name carries the source's sha256, so a library left over
+    from any other source is never picked up."""
+    import hashlib
+    import os
+    with open(native._SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    path = native.lib_path()
+    assert os.path.basename(path) == f"_slu_host-{digest}.so"
+    native.require()
+    assert os.path.exists(path)
+
+
+def test_require_raises_when_library_unavailable(monkeypatch):
+    """require() names why there is no library instead of degrading to
+    the Python analysis; available() keeps the soft answer."""
+    monkeypatch.setenv("SLU_TPU_NO_NATIVE", "1")
+    native._tried, native._lib = False, None
+    try:
+        assert not native.available()
+        with pytest.raises(RuntimeError, match="SLU_TPU_NO_NATIVE"):
+            native.require()
+    finally:
+        monkeypatch.delenv("SLU_TPU_NO_NATIVE")
+        native._tried, native._lib = False, None
+    assert native.require() is not None

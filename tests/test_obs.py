@@ -909,7 +909,7 @@ def test_escalation_ladder_emits_rung_metrics(monkeypatch):
 
 def test_bench_row_carries_compile_and_phase_fields(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_NX="6",
-               BENCH_REPS="1", BENCH_NO_PROBE="1", BENCH_FORCE_CPU="1",
+               BENCH_REPS="1",
                BENCH_DEADLINE_S="240",
                SLU_TPU_FLIGHTREC=str(tmp_path / "bench_fr.json"))
     env.pop("SLU_TPU_TRACE", None)

@@ -305,3 +305,62 @@ def test_host_share_split_matches_plain():
     grid = gridinit(4, 2)
     exm = StreamExecutor(plan, "float64", mesh=grid.mesh, host_flops=1e7)
     assert exm.host_levels == 0
+
+
+def test_host_share_step_keeps_jit_when_key_matches_a_device_step():
+    """A host-share step whose shape key equals a device step's must not
+    run the ahead-of-time executable compiled for the device: that one
+    is bound to the device's placement.  Device steps here run on a
+    second CPU device, so the host steps' placement differs; the result
+    stays bit-equal to the unsplit stream, every device step runs an
+    ahead-of-time executable and every host step the jitted kernel."""
+    from superlu_dist_tpu.models.gallery import poisson3d
+    from superlu_dist_tpu.numeric.stream import (StreamExecutor, _bucket_len,
+                                                 _kernel)
+    from superlu_dist_tpu.ops.dense import pivot_kernel
+    from superlu_dist_tpu.symbolic.symbfact import _front_flops
+
+    a = poisson3d(6)
+    sym = symmetrize_pattern(a)
+    col_order = get_perm_c(Options(), a, sym)
+    sf = symbolic_factorize(sym, col_order, relax=4, max_supernode=16,
+                            amalg_tol=0.0)
+    plan = build_plan(sf)
+    avals = sym.data[sf.value_perm]
+    thresh = np.sqrt(np.finfo(np.float64).eps) * a.norm_max()
+    lv_cost = {}
+    for g in plan.groups:
+        fl = _bucket_len(g.batch, 1) * _front_flops(g.w, g.u)
+        lv_cost[g.level] = max(lv_cost.get(g.level, 0), fl)
+    # the first threshold whose host prefix shares a key with a later,
+    # device-side level
+    for cut in sorted(set(lv_cost.values())):
+        ex = StreamExecutor(plan, "float64", host_flops=cut + 1)
+        if ({k for k, _, _, _, on in ex._steps if on}
+                & {k for k, _, _, _, on in ex._steps if not on}):
+            break
+    with jax.default_device(jax.devices()[1]):
+        ref = StreamExecutor(plan, "float64", host_flops=0)(
+            jnp.asarray(avals), jnp.asarray(thresh))
+        ex = StreamExecutor(plan, "float64", host_flops=cut + 1)
+        host = {key for key, _, _, _, on in ex._steps if on}
+        dev = {key for key, _, _, _, on in ex._steps if not on}
+        assert host & dev, "plan must share a key across the split"
+        out = ex(jnp.asarray(avals), jnp.asarray(thresh))
+        pivot = pivot_kernel()
+        avals_d = jnp.asarray(avals)
+        pool = jnp.zeros(plan.pool_size)
+        thresh_d = jnp.asarray(thresh)
+        cpu0 = jax.devices()[0]
+        for key, arrs, child_arrs, _, on_host in ex._steps:
+            head = ((jax.device_put(avals_d, cpu0),
+                     jax.device_put(pool, cpu0),
+                     jax.device_put(thresh_d, cpu0)) if on_host
+                    else (avals_d, pool, thresh_d))
+            kern = ex._get_kernel(key, pivot, (*head, *arrs, *child_arrs))
+            jitted = _kernel(*key, None, False, pivot, ex.gemm_prec)
+            assert (kern is jitted) == on_host, (key, on_host)
+    assert int(out[1]) == int(ref[1])
+    for (lp, up), (rlp, rup) in zip(out[0], ref[0]):
+        np.testing.assert_array_equal(np.asarray(lp), np.asarray(rlp))
+        np.testing.assert_array_equal(np.asarray(up), np.asarray(rup))

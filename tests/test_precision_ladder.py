@@ -1,15 +1,12 @@
 """Throughput-ladder tests: mixed-precision Schur GEMMs with BERR-gated
-escalation (ops/dense.gemm_precision, drivers/gssvx gemm-precision rung)
-and the Pallas fused gather/scatter kernels (numeric/pallas_kernels.py).
+escalation (ops/dense.gemm_precision, drivers/gssvx gemm-precision rung).
 
 The contract under test (docs/PERFORMANCE.md, throughput ladder):
 
 * every GEMM tier DELIVERS componentwise BERR at or below the gate —
   reduced tiers may escalate (the rung is recorded), but a failing X is
   never returned as converged;
-* the executors stay bitwise-identical to each other WITHIN a tier, and
-  the Pallas extend-add/assembly path is bitwise-identical to the
-  ``.at[]`` lowering (so every older equivalence gate carries over);
+* the executors stay bitwise-identical to each other WITHIN a tier;
 * a checkpoint frontier computed at one tier refuses to resume under
   another tier's arithmetic.
 """
@@ -21,8 +18,7 @@ import pytest
 from superlu_dist_tpu.drivers.gssvx import gssvx
 from superlu_dist_tpu.models.gallery import (
     hilbert, poisson2d, rank_deficient_arrowhead)
-from superlu_dist_tpu.numeric.factor import (
-    extend_add_set, numeric_factorize)
+from superlu_dist_tpu.numeric.factor import numeric_factorize
 from superlu_dist_tpu.numeric.plan import build_plan
 from superlu_dist_tpu.ops.dense import (
     GEMM_PREC_LADDER, gemm, gemm_precision, next_gemm_precision)
@@ -117,8 +113,7 @@ def test_gemm_helper_semantics():
 def test_new_knobs_registry_routed():
     """SLU104 satellite: the ladder knobs are registry-declared, so the
     slulint env rule covers their reads (the tree scans clean)."""
-    for name in ("SLU_TPU_GEMM_PREC", "SLU_TPU_PALLAS",
-                 "SLU_TPU_PEAK_GFLOPS"):
+    for name in ("SLU_TPU_GEMM_PREC", "SLU_TPU_PEAK_GFLOPS"):
         assert name in KNOB_REGISTRY, name
 
 
@@ -203,7 +198,7 @@ def test_well_conditioned_fast_tier_no_rungs():
 
 
 # ---------------------------------------------------------------------------
-# executor equivalence per tier + Pallas bitwise contract
+# executor equivalence per tier
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("tier", ["bf16", "highest"])
@@ -237,85 +232,6 @@ def test_tiers_actually_differ_bf16():
                                         gemm_prec="bf16"))
     assert any((h[0] != l[0]).any() or (h[1] != l[1]).any()
                for h, l in zip(hi, lo))
-
-
-def test_pallas_extend_add_unit_bitwise():
-    """Unit contract: the Pallas extend-add equals the .at[] lowering
-    BITWISE, padded sentinels (OOB pool offset, OOB slot, rel == m)
-    included."""
-    from superlu_dist_tpu.numeric.pallas_kernels import (
-        extend_add_set_pallas)
-    rng = np.random.default_rng(3)
-    m, ub, batch, pool_len = 12, 5, 3, 200
-    pool = jnp.asarray(rng.standard_normal(pool_len), dtype=jnp.float32)
-    f = jnp.asarray(rng.standard_normal((batch, m * m)),
-                    dtype=jnp.float32)
-    child_off = jnp.asarray([0, 25, 50, pool_len])   # last = padding
-    child_slot = jnp.asarray([1, 0, 1, batch])
-    rel = np.full((4, ub), m, dtype=np.int64)
-    for c in range(3):
-        rel[c, :4] = rng.choice(m, size=4, replace=False)
-    rel = jnp.asarray(rel)
-    ref = extend_add_set(f, pool, m, ub, child_off, child_slot, rel)
-    out = extend_add_set_pallas(f, pool, m, ub, child_off, child_slot,
-                                rel, mode="interpret")
-    assert (np.asarray(ref) == np.asarray(out)).all()
-
-
-def test_pallas_assembly_unit_bitwise():
-    from superlu_dist_tpu.numeric.pallas_kernels import (
-        assemble_avals_pallas)
-    rng = np.random.default_rng(4)
-    batch, m, n_avals, la = 4, 9, 50, 37
-    avals = jnp.asarray(rng.standard_normal(n_avals), dtype=jnp.float32)
-    f = jnp.asarray(rng.standard_normal((batch, m * m)),
-                    dtype=jnp.float32)
-    pairs = rng.choice(batch * m * m, size=30, replace=False)
-    a_slot = np.concatenate([pairs // (m * m), np.full(la - 30, batch)])
-    a_flat = np.concatenate([pairs % (m * m),
-                             np.zeros(la - 30, dtype=np.int64)])
-    a_src = np.concatenate([rng.integers(0, n_avals, 30),
-                            np.full(la - 30, n_avals)])
-    a_slot, a_flat, a_src = map(jnp.asarray, (a_slot, a_flat, a_src))
-    vals = avals.at[a_src].get(mode="fill", fill_value=0)
-    ref = f.at[(a_slot, a_flat)].add(vals, mode="drop")
-    out = assemble_avals_pallas(f, avals, a_slot, a_flat, a_src,
-                                mode="interpret")
-    assert (np.asarray(ref) == np.asarray(out)).all()
-
-
-@pytest.mark.parametrize("executor", ["fused", "stream", "mega"])
-def test_pallas_end_to_end_bitwise(executor, monkeypatch):
-    """The real factor path under SLU_TPU_PALLAS=interpret is bitwise
-    vs the .at[] lowering, per executor (assembly + extend-add both
-    exercised)."""
-    a = poisson2d(14)
-    plan, vals, anorm = _analyzed(a, closed=True)
-    monkeypatch.delenv("SLU_TPU_PALLAS", raising=False)
-    base = _host_fronts(numeric_factorize(plan, vals, anorm,
-                                          dtype="float32",
-                                          executor=executor))
-    monkeypatch.setenv("SLU_TPU_PALLAS", "interpret")
-    pal = _host_fronts(numeric_factorize(plan, vals, anorm,
-                                         dtype="float32",
-                                         executor=executor))
-    for (bl, bu), (pl_, pu) in zip(base, pal):
-        assert (bl == pl_).all() and (bu == pu).all()
-
-
-def test_pallas_mode_resolution(monkeypatch):
-    from superlu_dist_tpu.numeric.pallas_kernels import pallas_mode
-    monkeypatch.delenv("SLU_TPU_PALLAS", raising=False)
-    assert pallas_mode() == "off"        # auto on a CPU backend
-    monkeypatch.setenv("SLU_TPU_PALLAS", "0")
-    assert pallas_mode() == "off"
-    monkeypatch.setenv("SLU_TPU_PALLAS", "interpret")
-    assert pallas_mode() == "interpret"
-    monkeypatch.setenv("SLU_TPU_PALLAS", "1")
-    assert pallas_mode() == "interpret"  # forced-on degrades off-TPU
-    monkeypatch.setenv("SLU_TPU_PALLAS", "nope")
-    with pytest.raises(ValueError):
-        pallas_mode()
 
 
 # ---------------------------------------------------------------------------
@@ -360,11 +276,14 @@ def test_peak_detection_and_mfu(monkeypatch):
     assert peak > 0 and src.startswith("measured:")
     pct, _, _ = mfu_pct(peak / 100.0, "default")
     assert pct > 0.0         # never rounds a real rate down to 0.0
-    # jax-free table accessor: tier pass-counts divide the bf16 peak
-    assert table_peak_gflops("TPU v5e", "bf16") == 197_000.0
-    assert table_peak_gflops("TPU v5e", "highest") == pytest.approx(
+    # jax-free table accessor keyed by the device_kind a v5e reports:
+    # tier pass-counts divide the bf16 peak, an unknown kind raises
+    assert table_peak_gflops("TPU v5 lite", "bf16") == 197_000.0
+    assert table_peak_gflops("TPU v5 lite", "highest") == pytest.approx(
         197_000.0 / 6)
-    assert table_peak_gflops("A100", "bf16") is None
+    for unknown in ("A100", "TPU v5e", "tpu"):
+        with pytest.raises(KeyError):
+            table_peak_gflops(unknown, "bf16")
 
 
 def test_bench_history_key_is_precision_tagged():

@@ -116,21 +116,23 @@ def test_slu112_argument_passed_twin_clean():
 # SLU114 SPMD collective lockstep
 # --------------------------------------------------------------------------
 
-def _shard_mapped(body):
-    from jax.experimental.shard_map import shard_map
+def _shard_mapped(body, check_vma=True):
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
-                             out_specs=P("x")))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                                 out_specs=P("x"), check_vma=check_vma))
 
 
 def test_slu114_divergent_branch_collectives_flagged():
+    # branches whose outputs differ in varying axes do not trace under
+    # the default check, so the divergent program is built the way the
+    # SPMD tier builds its own: check_vma=False (parallel/spmd.py)
     def body(a):
         return jax.lax.cond(a.sum() > 0,
                             lambda v: jax.lax.psum(v, "x"),
                             lambda v: v * 1.0, a)
 
-    spec = trace_spec(_shard_mapped(body), (np.ones(4),),
+    spec = trace_spec(_shard_mapped(body, check_vma=False), (np.ones(4),),
                       label="divergent", site="test", mesh_axes=("x",))
     findings, _ = audit_spec(spec, donate_min_bytes=BIG,
                              const_max_bytes=BIG)
@@ -150,8 +152,12 @@ def test_slu114_matched_branch_collectives_clean():
                              const_max_bytes=BIG)
     assert findings == []
     # the agreed branch sequence is inlined once into the program's
-    # collective sequence
-    assert collective_sequence(spec.jaxpr) == [("psum2", ("x",))]
+    # collective sequence (psum is psum_invariant under the vma check)
+    assert collective_sequence(spec.jaxpr) == [("psum_invariant", ("x",))]
+    unchecked = trace_spec(_shard_mapped(body, check_vma=False),
+                           (np.ones(4),), label="matched", site="test",
+                           mesh_axes=("x",))
+    assert collective_sequence(unchecked.jaxpr) == [("psum", ("x",))]
 
 
 def test_slu114_off_mesh_axis_flagged_on_stub():
@@ -191,7 +197,6 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 os.environ["SLU_TPU_VERIFY_PROGRAMS"] = "1"
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from superlu_dist_tpu.utils.programaudit import maybe_audit
 from superlu_dist_tpu.utils.errors import ProgramAuditError
@@ -207,14 +212,14 @@ def divergent(a):
                         lambda v: v * 1.0, a)
 
 x = np.arange(8.0)
-ok = jax.jit(shard_map(matched, mesh=mesh, in_specs=P("x"),
-                       out_specs=P("x")))
+ok = jax.jit(jax.shard_map(matched, mesh=mesh, in_specs=P("x"),
+                           out_specs=P("x")))
 maybe_audit("test", "matched", ok, (x,), mesh_axes=("x",))
 out = np.asarray(ok(x))
 assert np.allclose(out[:4] + out[4:], x[:4] + x[4:] + out[:4]), out
 
-bad = jax.jit(shard_map(divergent, mesh=mesh, in_specs=P("x"),
-                        out_specs=P("x")))
+bad = jax.jit(jax.shard_map(divergent, mesh=mesh, in_specs=P("x"),
+                            out_specs=P("x"), check_vma=False))
 try:
     maybe_audit("test", "divergent", bad, (x,), mesh_axes=("x",))
 except ProgramAuditError as e:

@@ -1,5 +1,5 @@
 """shard_map SPMD tier (parallel/spmd.py) — one compiled program per
-factor (and per solve-sweep bucket) over a real jax.Mesh.
+factor group (and per solve-sweep bucket) over a real jax.Mesh.
 
 The bitwise contract this suite pins (the PR 5 pattern): the SPMD
 program's L/U factors AND solve vectors are bit-identical to the
@@ -8,20 +8,17 @@ twins of each other) on the 8-virtual-device CPU mesh.  That is what
 lets the TreeComm host-lockstep tier stand as the A/B reference: any
 SPMD result can be re-derived lockstep and compared exactly.
 
-Also covered: the two composition debts this tier cleared — the mega
-executor runs its bucketed programs UNDER the mesh (no auto-downgrade
-to stream; GSPMD re-tiling makes that an allclose-class contract, see
-numeric/mega.py), and Pallas interpret-mode kernels ride through
-shard_map bitwise — plus auditor cleanliness (SLU_TPU_VERIFY_SHARDING
-/ SLU_TPU_VERIFY_PROGRAMS) and checkpoint-frontier portability between
-the lockstep and SPMD entry points.
+Also covered: the mega executor runs its bucketed programs UNDER the
+mesh (no auto-downgrade to stream; GSPMD re-tiling makes that an
+allclose-class contract, see numeric/mega.py), plus auditor cleanliness
+(SLU_TPU_VERIFY_SHARDING / SLU_TPU_VERIFY_PROGRAMS) and checkpoint-
+frontier portability between the lockstep and SPMD entry points.
 """
 
 import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from superlu_dist_tpu.models.gallery import (helmholtz_2d, hilbert,
                                              poisson2d,
@@ -71,7 +68,7 @@ _GALLERY = [("poisson", lambda: poisson2d(16)),
 
 @pytest.mark.parametrize("name,make", _GALLERY)
 def test_spmd_bitwise_vs_lockstep(name, make):
-    """One shard_map program per factor, bit-identical L/U to EVERY
+    """shard_map programs per factor group, bit-identical L/U to EVERY
     single-device lockstep executor, and bit-identical solve/solveT."""
     mesh = _mesh()
     plan, vals, anorm = _analyzed(make())
@@ -109,11 +106,23 @@ def test_spmd_bitwise_complex_conjugate_sweeps():
 
 
 def test_spmd_is_one_program():
+    """One shard_map program per distinct group shape, all compiled
+    ahead of the first factorization's stream; a refactorization on
+    the same executor builds none."""
+    from superlu_dist_tpu.obs.compilestats import COMPILE_STATS
     mesh = _mesh()
     plan, vals, anorm = _analyzed(poisson2d(16))
     ex = get_executor(plan, "float64", executor="spmd", mesh=mesh)
     assert isinstance(ex, SpmdFactorExecutor)
-    assert ex.n_kernels == 1 and ex.granularity == "program"
+    assert ex.granularity == "group"
+    assert 1 <= ex.n_kernels <= len(plan.groups)
+    for _ in range(2):
+        mark = COMPILE_STATS.marker()
+        f = numeric_factorize(plan, vals, anorm, executor="spmd", mesh=mesh)
+        built = [r for r in COMPILE_STATS.records[mark:]
+                 if r.site == "spmd.factor"]
+        assert f.executor == "SpmdFactorExecutor"
+        assert len(built) == (ex.n_kernels if _ == 0 else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -150,7 +159,7 @@ def test_knobs_registered():
 
 
 # ---------------------------------------------------------------------------
-# composition debt 1: mega runs UNDER the mesh (no downgrade)
+# mega runs UNDER the mesh (no downgrade)
 # ---------------------------------------------------------------------------
 
 def test_mega_under_mesh_no_downgrade():
@@ -171,29 +180,6 @@ def test_mega_under_mesh_no_downgrade():
         for x0, x1 in ((l0, l1), (u0, u1)):
             assert np.allclose(np.asarray(x0), np.asarray(x1),
                                rtol=1e-12, atol=0)
-
-
-# ---------------------------------------------------------------------------
-# composition debt 2: Pallas rides through under the mesh
-# ---------------------------------------------------------------------------
-
-def test_pallas_interpret_under_mesh_bitwise():
-    """Interpret-mode Pallas kernels inside the shard_map program are
-    bitwise twins of the .at[] path — the old pin-OFF-under-mesh guard
-    is gone (numeric/pallas_kernels.py)."""
-    mesh = _mesh()
-    plan, vals, anorm = _analyzed(rank_deficient_arrowhead(40))
-    th = jnp.asarray(np.sqrt(np.finfo(np.float64).eps) * anorm)
-    v = jnp.asarray(vals)
-    ex0 = SpmdFactorExecutor(plan, "float64", mesh, pallas="off")
-    ex1 = SpmdFactorExecutor(plan, "float64", mesh, pallas="interpret")
-    assert ex1.pallas == "interpret"          # no silent pin to off
-    f0, t0 = ex0(v, th)
-    f1, t1 = ex1(v, th)
-    assert int(t0) == int(t1)
-    for (l0, u0), (l1, u1) in zip(f0, f1):
-        assert np.array_equal(np.asarray(l0), np.asarray(l1))
-        assert np.array_equal(np.asarray(u0), np.asarray(u1))
 
 
 # ---------------------------------------------------------------------------
