@@ -410,7 +410,6 @@ def main():
             offload = "none"
             granularity = "fused"
             n_kernels = 1
-            last_profile = None
             last_dispatch_seconds = None
 
             def __init__(self):
@@ -556,24 +555,6 @@ def main():
              f"{plan.flops / dt / 1e9:.1f} GFLOP/s")
     fronts, tiny = out
     RESULT["tiny_pivots"] = int(tiny)
-    # legacy stderr kernel lines only under the (deprecated)
-    # SLU_TPU_PROFILE knob — the tracer's structured kernel spans are the
-    # first-class record (last_profile also fills whenever tracing is on)
-    from superlu_dist_tpu.utils.options import deprecated_knob_warning
-    deprecated_knob_warning(
-        "SLU_TPU_PROFILE",
-        "set SLU_TPU_TRACE=trace.json instead — the tracer's kernel "
-        "spans are the structured record of the same timings")
-    if ex.last_profile and os.environ.get("SLU_TPU_PROFILE"):
-        # kernel-shape trace (dgemm_mnk.dat analog) to stderr, top by time
-        top = sorted(ex.last_profile, key=lambda r: -r["seconds"])[:15]
-        for r in top:
-            print(f"# lvl={r['level']:<3d} B={r['batch']:<5d} "
-                  f"m={r['m']:<5d} w={r['w']:<5d} u={r['u']:<5d} "
-                  f"{r['seconds'] * 1e3:8.2f} ms "
-                  f"{r['gflop'] / max(r['seconds'], 1e-12):8.1f} GF/s",
-                  file=sys.stderr)
-
     # Everything past this point (solve, residual, CPU baseline) must not
     # be able to zero the factor GFLOPS: each phase degrades independently
     # and the JSON line always prints.

@@ -6,7 +6,8 @@ Runs a small factor+solve in fresh subprocesses:
 
 * everything OFF — asserts the disabled paths allocate NO per-event
   telemetry objects: the process-global tracer stays the NULL_TRACER
-  singleton (reused no-op span), ``obs.metrics.get_metrics()`` stays
+  singleton (its spans feed only the profiler sink, with the no-op
+  span as their sink), ``obs.metrics.get_metrics()`` stays
   the NULL_METRICS singleton (no counter dict entries), and
   ``obs.flightrec.get_flightrec()`` stays the NULL_FLIGHTREC singleton
   (no ring, no signal handler, no artifact file);
@@ -57,7 +58,7 @@ snap = m.snapshot()
 out = {
     "tracer": type(t).__name__,
     "null_singleton": t is trace.NULL_TRACER,
-    "span_reused": t.span("a") is t.span("b"),
+    "span_sinkless": t.span("a")._sink is trace.NULL_SPAN,
     "fact_seconds": stats.utime["FACT"],
     "compile_builds": stats.compile.get("builds", 0),
     "metrics": type(m).__name__,
@@ -127,8 +128,8 @@ def main():
     off = run_child({})
     if off["tracer"] != "NullTracer" or not off["null_singleton"]:
         fail(f"disabled path allocated a tracer: {off}")
-    if not off["span_reused"]:
-        fail("disabled path did not reuse the no-op span object")
+    if not off["span_sinkless"]:
+        fail("disabled path gave a span a sink beyond the profiler")
     if os.path.exists(trace_path) or os.path.exists(jsonl_path):
         fail("disabled path created a trace artifact")
     if off["metrics"] != "NullMetrics" or not off["metrics_null"]:

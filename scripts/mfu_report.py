@@ -9,9 +9,9 @@ trace in EITHER format:
   (superlu_dist_tpu/obs/trace.py) — kernel spans carry shape, executed
   vs structural flops and the padding ratio natively, no scraping;
 * the legacy stderr log containing ``# lvl=... m=... w=... u=...``
-  kernel-trace lines emitted by bench.py under (deprecated)
-  SLU_TPU_PROFILE=1 — the reference's dgemm_mnk.dat analog
-  (SRC/pdgstrf.c:380-387).
+  kernel-trace lines that older bench.py versions printed — the
+  reference's dgemm_mnk.dat analog (SRC/pdgstrf.c:380-387); logs kept
+  from those runs stay readable.
 
 The second argument is sniffed: trace formats are parsed natively,
 anything else falls back to the legacy regex.  Missing or empty inputs

@@ -21,11 +21,13 @@ A·x = b is solved as
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 
 import numpy as np
 
+from superlu_dist_tpu.obs.trace import get_tracer
 from superlu_dist_tpu.sparse.formats import SparseCSR, symmetrize_pattern
 from superlu_dist_tpu.utils.options import (
     Options, Fact, RowPerm, IterRefine, Trans, default_factor_dtype,
@@ -843,6 +845,21 @@ def _permuted_values(lu: LUFactorization):
     return sym.data[lu.sf.value_perm]
 
 
+@contextlib.contextmanager
+def _rung_span(report: SolveReport, name: str, **attrs):
+    """The ``rung`` span of one escalation rung (its refactor, solver and
+    refinement), closed with what the rung's record says: berr before and
+    after, and whether the ladder adopted it (berr strictly improved)."""
+    n0 = len(report.rungs)
+    with get_tracer().span(name, cat="rung", **attrs) as sp:
+        yield
+        if len(report.rungs) > n0:
+            r = report.rungs[-1]
+            sp.set(berr_before=float(r.berr_before),
+                   berr_after=float(r.berr_after),
+                   adopted=bool(r.berr_after < r.berr_before))
+
+
 def _escalate(options: Options, a: SparseCSR, op, b: np.ndarray,
               lu: LUFactorization, stats: Stats, trans, solve_fn,
               x: np.ndarray, residual_dtype, report: SolveReport,
@@ -909,8 +926,9 @@ def _escalate(options: Options, a: SparseCSR, op, b: np.ndarray,
     # exact residual is the cheapest repair)
     if (np.dtype(residual_dtype) != np.float64
             and len(report.rungs) < recovery.max_rungs):
-        done = attempt("residual-precision", "float64 residual",
-                       solve_fn, np.float64, cur_x)
+        with _rung_span(report, "residual-precision", dtype="float64"):
+            done = attempt("residual-precision", "float64 residual",
+                           solve_fn, np.float64, cur_x)
 
     # ---- rung 1.5: gemm-precision ladder ------------------------------------
     # The throughput-ladder safety net (docs/PERFORMANCE.md): a reduced
@@ -928,26 +946,27 @@ def _escalate(options: Options, a: SparseCSR, op, b: np.ndarray,
         bvals = _permuted_values(lu)
         if bvals is None:
             break
-        t0 = time.perf_counter()
-        lu_prec = dataclasses.replace(
-            lu, numeric=None, dev_solver=None, dev_spmv=None, berrs=None,
-            options=dataclasses.replace(options, gemm_prec=nxt))
-        try:
-            info_p = factorize_numeric(lu_prec, bvals, stats)
-        except SuperLUError as e:
-            report.rungs.append(RungRecord(
-                name="gemm-precision", detail=f"{nxt}: {type(e).__name__}",
-                berr_before=cur_berr,
-                seconds=time.perf_counter() - t0))
-            break
-        if info_p != 0:
-            report.rungs.append(RungRecord(
-                name="gemm-precision", detail=f"{nxt}: info={info_p}",
-                berr_before=cur_berr,
-                seconds=time.perf_counter() - t0))
-            break
-        solve_p = _trans_solver(lu_prec, trans, a_dtype)
-        done = attempt("gemm-precision", nxt, solve_p, np.float64, cur_x)
+        with _rung_span(report, "gemm-precision", tier=nxt):
+            t0 = time.perf_counter()
+            lu_prec = dataclasses.replace(
+                lu, numeric=None, dev_solver=None, dev_spmv=None, berrs=None,
+                options=dataclasses.replace(options, gemm_prec=nxt))
+            try:
+                info_p = factorize_numeric(lu_prec, bvals, stats)
+            except SuperLUError as e:
+                report.rungs.append(RungRecord(
+                    name="gemm-precision", detail=f"{nxt}: {type(e).__name__}",
+                    berr_before=cur_berr,
+                    seconds=time.perf_counter() - t0))
+                break
+            if info_p != 0:
+                report.rungs.append(RungRecord(
+                    name="gemm-precision", detail=f"{nxt}: info={info_p}",
+                    berr_before=cur_berr,
+                    seconds=time.perf_counter() - t0))
+                break
+            solve_p = _trans_solver(lu_prec, trans, a_dtype)
+            done = attempt("gemm-precision", nxt, solve_p, np.float64, cur_x)
         adopted = solve_fn is solve_p
         if adopted:                   # adopted: the answer now rests on
             lu_eff = lu_prec          # the higher-tier factors
@@ -964,23 +983,24 @@ def _escalate(options: Options, a: SparseCSR, op, b: np.ndarray,
             and len(report.rungs) < recovery.max_rungs):
         bvals = _permuted_values(lu)
         if bvals is not None:
-            # dtype escalation subsumes the gemm ladder: the hiprec
-            # refactor always runs at the top GEMM tier
-            lu_esc = dataclasses.replace(
-                lu, numeric=None, dev_solver=None, dev_spmv=None,
-                berrs=None,
-                options=dataclasses.replace(options, factor_dtype=esc,
-                                            gemm_prec="highest"))
-            try:
-                info2 = factorize_numeric(lu_esc, bvals, stats)
-            except SuperLUError:
-                info2 = -1
-            if info2 == 0:
-                solve2 = _trans_solver(lu_esc, trans, a_dtype)
-                done = attempt("hiprec-factors", esc, solve2,
-                               np.float64, cur_x)
-                if solve_fn is solve2:    # adopted: hand the caller the
-                    lu_eff = lu_esc       # factors the answer rests on
+            with _rung_span(report, "hiprec-factors", dtype=esc):
+                # dtype escalation subsumes the gemm ladder: the hiprec
+                # refactor always runs at the top GEMM tier
+                lu_esc = dataclasses.replace(
+                    lu, numeric=None, dev_solver=None, dev_spmv=None,
+                    berrs=None,
+                    options=dataclasses.replace(options, factor_dtype=esc,
+                                                gemm_prec="highest"))
+                try:
+                    info2 = factorize_numeric(lu_esc, bvals, stats)
+                except SuperLUError:
+                    info2 = -1
+                if info2 == 0:
+                    solve2 = _trans_solver(lu_esc, trans, a_dtype)
+                    done = attempt("hiprec-factors", esc, solve2,
+                                   np.float64, cur_x)
+                    if solve_fn is solve2:    # adopted: hand the caller the
+                        lu_eff = lu_esc       # factors the answer rests on
 
     # ---- rung 3: refactor with re-scaling / re-ordering ---------------------
     # only when it would actually change something the first pass didn't do
@@ -989,48 +1009,50 @@ def _escalate(options: Options, a: SparseCSR, op, b: np.ndarray,
                     or not options.replace_tiny_pivot
                     or esc is not None)
     if not done and would_change and len(report.rungs) < recovery.max_rungs:
-        t0 = time.perf_counter()
-        opts3 = dataclasses.replace(
-            options, fact=Fact.DOFACT, equil=True,
-            row_perm=RowPerm.LargeDiag_MC64, replace_tiny_pivot=True,
-            factor_dtype=esc if esc is not None else options.factor_dtype,
-            gemm_prec="highest",        # the last rung gambles nothing
-            iter_refine=IterRefine.SLU_DOUBLE, print_stat=False,
-            user_perm_r=None,
-            # no recursion, no mid-ladder raises: the ladder itself is
-            # the consumer of this sub-solve's report
-            recovery=dataclasses.replace(recovery, enabled=False,
-                                         condest="never", sentinels=False))
-        try:
-            x3, lu3, stats3, info3 = gssvx(opts3, a, b)
-        except SuperLUError as e:
-            x3, lu3, stats3, info3 = None, None, None, -1
-            err3 = type(e).__name__
-        if info3 == 0 and x3 is not None:
-            rep3 = stats3.solve_report
-            berr3 = (rep3.berr if rep3 is not None
-                     and rep3.berr is not None else float("inf"))
-            if not np.all(np.isfinite(np.asarray(x3))):
-                berr3 = float("inf")
-            report.rungs.append(RungRecord(
-                name="refactor-rescale", detail=str(opts3.factor_dtype),
-                berr_before=cur_berr, berr_after=berr3,
-                seconds=time.perf_counter() - t0))
-            if rep3 is not None:
-                report.berr_history.extend(rep3.berr_history)
-            if berr3 < cur_berr:
-                cur_x, cur_berr, lu_eff = np.asarray(x3), berr3, lu3
-                solve_fn = _trans_solver(lu3, trans, a_dtype)
-                residual_dtype = np.float64
-                report.berr = berr3
-                report.tiny_pivots = rep3.tiny_pivots if rep3 else 0
-        else:
-            report.rungs.append(RungRecord(
-                name="refactor-rescale",
-                detail=f"failed: info={info3}"
-                       + (f" ({err3})" if info3 == -1 else ""),
-                berr_before=cur_berr,
-                seconds=time.perf_counter() - t0))
+        with _rung_span(report, "refactor-rescale",
+                        dtype=str(esc or options.factor_dtype)):
+            t0 = time.perf_counter()
+            opts3 = dataclasses.replace(
+                options, fact=Fact.DOFACT, equil=True,
+                row_perm=RowPerm.LargeDiag_MC64, replace_tiny_pivot=True,
+                factor_dtype=esc if esc is not None else options.factor_dtype,
+                gemm_prec="highest",        # the last rung gambles nothing
+                iter_refine=IterRefine.SLU_DOUBLE, print_stat=False,
+                user_perm_r=None,
+                # no recursion, no mid-ladder raises: the ladder itself is
+                # the consumer of this sub-solve's report
+                recovery=dataclasses.replace(recovery, enabled=False,
+                                             condest="never", sentinels=False))
+            try:
+                x3, lu3, stats3, info3 = gssvx(opts3, a, b)
+            except SuperLUError as e:
+                x3, lu3, stats3, info3 = None, None, None, -1
+                err3 = type(e).__name__
+            if info3 == 0 and x3 is not None:
+                rep3 = stats3.solve_report
+                berr3 = (rep3.berr if rep3 is not None
+                         and rep3.berr is not None else float("inf"))
+                if not np.all(np.isfinite(np.asarray(x3))):
+                    berr3 = float("inf")
+                report.rungs.append(RungRecord(
+                    name="refactor-rescale", detail=str(opts3.factor_dtype),
+                    berr_before=cur_berr, berr_after=berr3,
+                    seconds=time.perf_counter() - t0))
+                if rep3 is not None:
+                    report.berr_history.extend(rep3.berr_history)
+                if berr3 < cur_berr:
+                    cur_x, cur_berr, lu_eff = np.asarray(x3), berr3, lu3
+                    solve_fn = _trans_solver(lu3, trans, a_dtype)
+                    residual_dtype = np.float64
+                    report.berr = berr3
+                    report.tiny_pivots = rep3.tiny_pivots if rep3 else 0
+            else:
+                report.rungs.append(RungRecord(
+                    name="refactor-rescale",
+                    detail=f"failed: info={info3}"
+                           + (f" ({err3})" if info3 == -1 else ""),
+                    berr_before=cur_berr,
+                    seconds=time.perf_counter() - t0))
 
     # the tier/dtype the delivered answer actually rests on (lu_eff may
     # be an escalated handle from any rung above)

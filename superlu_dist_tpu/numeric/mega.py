@@ -223,17 +223,16 @@ class MegaExecutor(StreamExecutor):
         # XLA compile below ever runs (SLU_TPU_VERIFY_PROGRAMS=1)
         self._audit_program(self._census_site, self._census_label(key),
                             jfn, sds)
-        t0 = time.perf_counter()
-        traced = jfn.trace(*sds)
-        t1 = time.perf_counter()
-        lowered = traced.lower()
-        t2 = time.perf_counter()
-        compiled = lowered.compile()
-        t3 = time.perf_counter()
-        COMPILE_STATS.record(
-            self._census_site, self._census_label(key), t0, t3 - t0,
-            n_args=len(args), trace_seconds=t1 - t0,
-            lower_seconds=t2 - t1, compile_seconds=t3 - t2)
+        with COMPILE_STATS.build(self._census_site, self._census_label(key),
+                                 n_args=len(args)) as b:
+            t0 = time.perf_counter()
+            traced = jfn.trace(*sds)
+            t1 = time.perf_counter()
+            lowered = traced.lower()
+            t2 = time.perf_counter()
+            compiled = lowered.compile()
+            b.trace_seconds, b.lower_seconds = t1 - t0, t2 - t1
+            b.compile_seconds = time.perf_counter() - t2
         self._mega_fns[(key, pivot)] = compiled
         return compiled
 

@@ -21,6 +21,7 @@ keys), not O(#groups).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import sys
@@ -38,7 +39,7 @@ from superlu_dist_tpu.obs.metrics import get_metrics
 from superlu_dist_tpu.obs.trace import NULL_TRACER, get_tracer
 from superlu_dist_tpu.symbolic.symbfact import _front_flops
 from superlu_dist_tpu.utils.lockwatch import make_lock
-from superlu_dist_tpu.utils.options import env_flag, env_float, env_int
+from superlu_dist_tpu.utils.options import env_float, env_int
 
 #: Shape keys whose first (compiling) invocation the compile census has
 #: already accounted — process-wide, mirroring the lru cache on _kernel.
@@ -76,22 +77,26 @@ def build_workers(n: int) -> int:
     return max(1, min(n, os.cpu_count() or 1, mem // 2 // BUILD_BYTES))
 
 
-def compile_all(lowered: list, progress: bool = False, label=str):
+def compile_all(lowered: list, progress: bool = False, label=str,
+                build=None):
     """Compile ``lowered`` (a list of jax ``Lowered``) in parallel threads
-    — XLA compiles outside the GIL.  Yields (index, executable, seconds)
-    in input order."""
+    — XLA compiles outside the GIL.  ``build(i)``, when given, is the
+    context manager each compile runs inside, on its thread (the compile
+    census's build span).  Yields (index, executable, seconds) in input
+    order."""
     workers = build_workers(len(lowered))
     if progress:
         print(f"[build] {len(lowered)} programs on {workers} threads",
               file=sys.stderr, flush=True)
 
-    def build(low):
+    def one(i):
         t0 = time.perf_counter()
-        exe = low.compile()
+        with build(i) if build is not None else contextlib.nullcontext():
+            exe = lowered[i].compile()
         return exe, time.perf_counter() - t0
 
     with ThreadPoolExecutor(workers) as ex:
-        for i, (exe, secs) in enumerate(ex.map(build, lowered)):
+        for i, (exe, secs) in enumerate(ex.map(one, range(len(lowered)))):
             if progress:
                 print(f"[build] {i + 1}/{len(lowered)} {label(i)} in "
                       f"{secs:.1f}s", file=sys.stderr, flush=True)
@@ -207,6 +212,9 @@ def _kernel(dims, l_a, child_shapes, pool_size, dtype, mesh,
             pool = jax.lax.with_sharding_constraint(pool, pool_sharding)
         return out, pool, tiny
 
+    # the program's name carries its front shape, so a device trace's
+    # ops say which shape key they belong to
+    step.__name__ = "factor_b{}_m{}_w{}_u{}".format(*dims)
     # pool is threaded linearly through the group stream — donating it lets
     # XLA scatter in place instead of copying pool_size entries per group
     return jax.jit(step, donate_argnums=(1,))
@@ -274,7 +282,6 @@ class StreamExecutor:
             offload = ("host" if padded > limit
                        and jax.default_backend() != "cpu" else "none")
         self.offload = offload
-        self.last_profile = None   # filled when SLU_TPU_PROFILE is set
         self.last_dispatch_seconds = None   # async-issue time of last call
         # time blocked materializing offloaded panels (D2H waits inside
         # the dispatch loop) — with last_dispatch_seconds this is the
@@ -442,26 +449,32 @@ class StreamExecutor:
             return
         lowered = []
         for (fn, _), (key, args) in todo.items():
-            if self._census_pending(key, pivot):
+            censused = self._census_pending(key, pivot)
+            if censused:
                 self._audit_program(self._census_site,
                                     self._census_label(key), fn, args)
+                _CENSUSED_KEYS.add(self._census_key(key, pivot))
             t0 = time.perf_counter()
             low = fn.lower(*(jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                   sharding=x.sharding)
                              for x in args))
-            lowered.append((key, low, t0, time.perf_counter() - t0,
-                            len(args)))
+            lowered.append((key, low, time.perf_counter() - t0, len(args),
+                            censused))
+
+        def build(i):
+            # the lowering ran here; the compile runs on a worker thread
+            key, _, t_lower, n_args, censused = lowered[i]
+            if not censused:
+                return contextlib.nullcontext()
+            return COMPILE_STATS.build(self._census_site,
+                                       self._census_label(key),
+                                       n_args=n_args, before=t_lower)
+
         tkeys = list(todo)
-        for i, exe, secs in compile_all(
+        for i, exe, _ in compile_all(
                 [low for _, low, _, _, _ in lowered], bool(self._progress),
-                lambda i: self._census_label(lowered[i][0])):
-            key, _, t0, t_lower, n_args = lowered[i]
+                lambda i: self._census_label(lowered[i][0]), build):
             _COMPILED[tkeys[i]] = exe
-            if self._census_pending(key, pivot):
-                _CENSUSED_KEYS.add(self._census_key(key, pivot))
-                COMPILE_STATS.record(self._census_site,
-                                     self._census_label(key), t0,
-                                     t_lower + secs, n_args=n_args)
 
     def _audit_program(self, site, label, fn, args) -> None:
         """Submit one program to the runtime IR auditor
@@ -483,10 +496,11 @@ class StreamExecutor:
         be timed into the census by the call loop)."""
         return self._census_key(key, pivot) not in _CENSUSED_KEYS
 
-    def _census_record(self, key, pivot, t0, n_args) -> None:
+    def _census_build(self, key, pivot, n_args):
+        """The build span and census record of a step's first call."""
         _CENSUSED_KEYS.add(self._census_key(key, pivot))
-        COMPILE_STATS.record(self._census_site, self._census_label(key),
-                             t0, time.perf_counter() - t0, n_args=n_args)
+        return COMPILE_STATS.build(self._census_site, self._census_label(key),
+                                   n_args=n_args)
 
     def _prep_avals(self, avals):
         """Upload/cast the pattern values (mega pads to its rung)."""
@@ -594,25 +608,15 @@ class StreamExecutor:
                                   pool_spec(self.mesh, self.pool_partition))
             avals = jax.device_put(avals, rep)
         # kernel-shape trace (the reference's PROFlevel GEMM trace,
-        # pdgstrf.c:380-387 -> dgemm_mnk.dat): per-group synchronous timing.
-        # NOTE: blocking per group serializes the async dispatch stream, so
-        # profiled runs measure per-kernel cost, not end-to-end overlap.
-        # The structured span tracer (obs/trace.py, SLU_TPU_TRACE) implies
-        # profiling for the same reason: its kernel spans must sum to the
-        # factor wall time, which only per-group blocking guarantees.
+        # pdgstrf.c:380-387 -> dgemm_mnk.dat): per-group synchronous
+        # timing under the file tracer (SLU_TPU_TRACE), whose kernel spans
+        # must sum to the factor wall time, which only per-group blocking
+        # guarantees.  Blocking serializes the async dispatch stream, so
+        # such runs measure per-kernel cost, not end-to-end overlap; the
+        # flight recorder and the profiler sink never block (a profiler
+        # trace of the named programs gives per-kernel device time)
         self._tracer = tracer = get_tracer()
-        # per-kernel blocking timing: file tracing implies it (kernel
-        # spans must sum to the FACT wall time); the flight recorder
-        # alone does NOT (tracer.profiling False) — its ring must not
-        # serialize the async dispatch stream
-        from superlu_dist_tpu.utils.options import deprecated_knob_warning
-        deprecated_knob_warning(
-            "SLU_TPU_PROFILE",
-            "set SLU_TPU_TRACE=trace.json instead — the tracer's "
-            "kernel spans carry the same per-kernel timings")
-        profile = env_flag("SLU_TPU_PROFILE") or tracer.profiling
-        if profile:
-            self.last_profile = []
+        profile = tracer.profiling
         # SLU_TPU_PROGRESS=K: log every K groups/levels issued (async
         # issue order, not completion) — hours-long runs are otherwise
         # silent between plan build and the final block_until_ready
@@ -674,12 +678,12 @@ class StreamExecutor:
                 print(f"[stream] issuing group {gi}/{len(self._steps)} "
                       f"(+{time.perf_counter() - t_issue0:.1f}s)",
                       file=sys.stderr, flush=True)
-            if cold or profile or tracer.enabled:
+            if profile or tracer.enabled:
                 t0 = time.perf_counter()
-            (lp, up), pool, t = kern(avals, pool, thresh, *a, *child_arrs)
-            if cold:
-                self._census_record(key, pivot, t0,
-                                    n_args=8 + len(child_arrs))
+            with (self._census_build(key, pivot, 8 + len(child_arrs))
+                  if cold else contextlib.nullcontext()):
+                (lp, up), pool, t = kern(avals, pool, thresh, *a,
+                                         *child_arrs)
             if tracer.enabled:
                 # async-issue span: how long the DISPATCH took (Python +
                 # transfer setup), before any blocking — the
@@ -692,11 +696,6 @@ class StreamExecutor:
                 dt = time.perf_counter() - t0
                 (b, m, w, u) = key[0]
                 grp = plan.groups[gi]
-                gflop = float(_front_flops(w, u)) * grp.batch / 1e9
-                self.last_profile.append({
-                    "level": grp.level, "batch": b, "m": m, "w": w, "u": u,
-                    "host": on_host,
-                    "seconds": dt, "gflop": gflop})
                 self._trace_kernel(t0, dt, grp.level, b, m, w, u,
                                    grp.batch, on_host)
             self._emit_front(fronts, lp, up, nreal, on_host)
@@ -928,13 +927,13 @@ class StreamExecutor:
                       f"({len(entries)} groups)", file=sys.stderr,
                       flush=True)
             tracer = self._tracer
-            if cold or profile or tracer.enabled:
+            if profile or tracer.enabled:
                 t0 = time.perf_counter()
-            outs, pool, t = fn(avals, pool, thresh, *flat)
-            if cold:
-                COMPILE_STATS.record(
-                    "stream._level_fn", f"level{level} g{len(entries)}",
-                    t0, time.perf_counter() - t0, n_args=3)
+            with (COMPILE_STATS.build("stream._level_fn",
+                                      f"level{level} g{len(entries)}",
+                                      n_args=3)
+                  if cold else contextlib.nullcontext()):
+                outs, pool, t = fn(avals, pool, thresh, *flat)
             tiny = tiny + t
             if tracer.enabled:
                 tracer.complete(f"issue lvl{level}", "dispatch", t0,
@@ -947,13 +946,6 @@ class StreamExecutor:
                             for g, _ in chunk) / 1e9
                 # a LEVEL aggregate, not one kernel's shape: m/w/u are
                 # maxima over the level's heterogeneous groups
-                self.last_profile.append({
-                    "level": level, "aggregate": True, "host": lv_host,
-                    "batch": sum(g.batch for g, _ in chunk),
-                    "m": max(g.m for g, _ in chunk),
-                    "w": max(g.w for g, _ in chunk),
-                    "u": max(g.u for g, _ in chunk),
-                    "seconds": dt, "gflop": gflop})
                 self._trace_kernel(
                     t0, dt, level,
                     sum(key[0][0] for key, *_ in entries),

@@ -9,24 +9,25 @@ sweep kernels) records one :class:`CompileRecord` per build — site,
 bucket key, build seconds, arg count, and whether the persistent
 XLA compile cache (``utils/jaxcache.py``) satisfied it from disk.
 
-Measurement model: ``jax.jit`` compiles synchronously inside the FIRST
-invocation for a given signature, so the executors time that first
-dispatch (which they already know is a build via their own key caches)
-and report it here — no second compile, no AOT staging on the hot path.
-The recorded ``seconds`` therefore include trace+lower+compile plus the
-(async) issue, which compile dominates by orders of magnitude on any
-build that matters.  ``scripts/compile_census.py --live`` provides the
-exact trace/lower/compile stage split offline, where double work is
+Measurement model: a build is the first call of a new jitted program
+(``jax.jit`` traces, lowers and compiles synchronously inside it, then
+issues the call asynchronously) or an ahead-of-time ``lower().compile()``.
+Each build site runs it inside :meth:`CompileStats.build`, which times
+it, records the census entry and opens the ``compile`` span
+(``slu.compile.<name>`` on the profiler's clock, ``name`` from
+:data:`SPAN_NAMES`), so a census record and a build span are one event.
+The recorded ``seconds`` include trace+lower+compile plus the (async)
+issue, which compile dominates by orders of magnitude on any build that
+matters.  ``scripts/compile_census.py --live`` provides the exact
+trace/lower/compile stage split offline, where double work is
 acceptable; records carry the split when a caller measured it.
 
-Persistent-cache attribution: ``jaxcache.enable_compile_cache`` notes
-the cache directory here; each record then checks whether the build
-appended a new entry file (disk MISS — XLA compiled and wrote) or not
-(disk HIT — loaded).  Without a configured cache dir the flag is None.
+Persistent-cache attribution: JAX's own events seen on the building
+thread during the build — ``/jax/compilation_cache/cache_misses``
+(XLA compiled and wrote: not a hit) or ``cache_hits`` (loaded from
+disk: a hit).  Without either (no persistent cache) the flag is None.
 
-The registry is always on: compiles are rare (O(#distinct kernels) per
-process), so unlike span/metric events there is no per-event hot-path
-cost to gate.  Consumers: the ``compile`` trace category
+Consumers: the ``compile`` trace category
 (obs/trace.py), the ``stats.compile`` block in the PStatPrint-analog
 report (utils/stats.py via drivers/gssvx.factorize_numeric), the
 ``compile_seconds`` / ``compile_census`` fields of the bench JSON row,
@@ -36,12 +37,56 @@ flight-recorder postmortems (obs/flightrec.py), and
 
 from __future__ import annotations
 
-import os
+import contextlib
 import threading
+import time
+import weakref
+from dataclasses import dataclass
 
 from superlu_dist_tpu.utils.lockwatch import make_lock
-import time
-from dataclasses import dataclass
+
+#: The ``compile`` span name (``slu.compile.<name>``) of each census site;
+#: a site not listed is its own name (``solve``, ``diag_inv``, ``spmv``).
+SPAN_NAMES = {"stream._kernel": "stream", "stream._level_fn": "stream",
+              "make_factor_fn": "fused", "mega._kernel": "mega",
+              "spmd.factor": "spmd"}
+
+_HIT = "/jax/compilation_cache/cache_hits"
+_MISS = "/jax/compilation_cache/cache_misses"
+#: JAX's persistent-cache events, counted per thread (a build and its
+#: cache lookup run on one thread; parallel builds run on several)
+_events = threading.local()
+_listening = []
+_listen_lock = make_lock("compilestats._listen_lock")
+
+
+def _on_event(name, **_):
+    if name == _HIT:
+        _events.hits = getattr(_events, "hits", 0) + 1
+    elif name == _MISS:
+        _events.misses = getattr(_events, "misses", 0) + 1
+
+
+def _cache_events() -> tuple:
+    """(hits, misses) seen on this thread so far."""
+    if not _listening:
+        import jax
+        with _listen_lock:
+            if not _listening:
+                jax.monitoring.register_event_listener(_on_event)
+                _listening.append(True)
+    return getattr(_events, "hits", 0), getattr(_events, "misses", 0)
+
+
+class Build:
+    """What a build site may add to its record: the exact stage split,
+    when it staged the build explicitly."""
+
+    __slots__ = ("trace_seconds", "lower_seconds", "compile_seconds")
+
+    def __init__(self):
+        self.trace_seconds = self.lower_seconds = None
+        self.compile_seconds = None
 
 
 @dataclass
@@ -73,10 +118,9 @@ class CompileStats:
         self._lock = make_lock("CompileStats._lock")
         self.records: list[CompileRecord] = []
         self._cache_dir: str | None = None
-        self._cache_entries: int | None = None
         # pending-key accounting: executors announce their FULL expected
         # kernel set at construction (they know it from the plan), and
-        # record() retires keys as they build — so a watchdog firing
+        # build() retires keys as they build — so a watchdog firing
         # mid-compile can name the shape keys still UNCOMPILED (the
         # BENCH_r02 postmortem gap: "died in factor-compile, 119
         # kernels" with no record of which were left)
@@ -90,55 +134,42 @@ class CompileStats:
     # ---- persistent-cache boundary (utils/jaxcache.py) -----------------
     def note_cache_dir(self, path: str | None) -> None:
         """jaxcache.enable_compile_cache announces the active persistent
-        cache directory; subsequent records attribute disk hit/miss by
-        entry-count delta."""
+        cache directory (reported in :meth:`block`)."""
         with self._lock:
             self._cache_dir = path
-            self._cache_entries = self._count_entries(path)
-
-    @staticmethod
-    def _count_entries(path: str | None) -> int | None:
-        if not path:
-            return None
-        try:
-            return len(os.listdir(path))
-        except OSError:
-            return None          # dir not created yet (first-ever compile)
 
     # ---- recording -----------------------------------------------------
-    def record(self, site: str, key: str, t0: float, seconds: float,
-               n_args: int = 0, builds: int = 1,
-               trace_seconds: float | None = None,
-               lower_seconds: float | None = None,
-               compile_seconds: float | None = None) -> CompileRecord:
-        """Account one build and emit a ``compile`` trace span (when
-        tracing is on).  ``t0`` is the ``time.perf_counter()`` at build
-        start so the span lands at the right trace position."""
-        hit = None
+    @contextlib.contextmanager
+    def build(self, site: str, key: str, n_args: int = 0,
+              before: float = 0.0):
+        """Run one program build inside the body: time it, read JAX's
+        persistent-cache events on this thread, open the ``compile`` span
+        and, when the body returns, record the census entry.  ``before``
+        is build work the site did ahead of the body (an AOT build's
+        lowering, on another thread) and counts into ``seconds``.  Yields
+        a :class:`Build` for an explicit stage split."""
+        from superlu_dist_tpu.obs.trace import get_tracer
+        hits0, misses0 = _cache_events()
+        t0 = time.perf_counter()
+        b = Build()
+        with get_tracer().span(SPAN_NAMES.get(site, site), "compile",
+                               site=site, key=key,
+                               n_args=int(n_args)) as sp:
+            yield b
+            hits, misses = _cache_events()
+            hit = (False if misses > misses0
+                   else True if hits > hits0 else None)
+            sp.set(persistent_hit=hit)
+        rec = CompileRecord(
+            site=site, key=key, t0=t0 - before,
+            seconds=before + time.perf_counter() - t0, n_args=int(n_args),
+            persistent_hit=hit,
+            trace_seconds=b.trace_seconds, lower_seconds=b.lower_seconds,
+            compile_seconds=b.compile_seconds)
         with self._lock:
-            n = self._count_entries(self._cache_dir)
-            if n is not None:
-                if self._cache_entries is not None:
-                    # no new entry file while a cache dir is live: the
-                    # executable came off disk, not out of the compiler
-                    hit = n <= self._cache_entries
-                self._cache_entries = n
-            rec = CompileRecord(site=site, key=key, seconds=float(seconds),
-                                t0=float(t0), n_args=int(n_args),
-                                builds=int(builds), persistent_hit=hit,
-                                trace_seconds=trace_seconds,
-                                lower_seconds=lower_seconds,
-                                compile_seconds=compile_seconds)
             self.records.append(rec)
             self._built.add((site, key))
             self._announced.discard((site, key))
-        from superlu_dist_tpu.obs.trace import get_tracer
-        tr = get_tracer()
-        if tr.enabled:
-            tr.complete(f"compile {site}", "compile", t0, seconds,
-                        key=key, n_args=int(n_args), builds=int(builds),
-                        persistent_hit=hit)
-        return rec
 
     # ---- pending-key accounting ----------------------------------------
     def announce(self, site: str, keys) -> None:
@@ -202,7 +233,7 @@ class CompileStats:
     # Export-path readers snapshot under the lock: a SolveServer
     # dispatcher (or scrubber postmortem) records builds concurrently
     # with a census/flightrec export, and an unlocked slice racing
-    # record()/_reset() tears the window (slulint SLU108's discipline,
+    # build()/_reset() tears the window (slulint SLU108's discipline,
     # applied to this singleton by hand — it spawns no thread itself).
     def _snap(self, since: int = 0) -> list:
         with self._lock:
@@ -278,17 +309,28 @@ class CompileStats:
 COMPILE_STATS = CompileStats()
 
 
-def record_build(site: str, key: str, t0: float, seconds: float,
-                 **kw) -> CompileRecord:
-    """Module-level convenience for the executors' build sites."""
-    return COMPILE_STATS.record(site, key, t0, seconds, **kw)
+#: per jitted program, the argument signatures it has been called with
+_CALLED = weakref.WeakKeyDictionary()
+_called_lock = make_lock("compilestats._called_lock")
 
 
-def timed_build(site: str, key: str, fn, *args, n_args: int = 0, **kwargs):
-    """Run ``fn(*args, **kwargs)`` (a first jit invocation) and record
-    its wall time as a build.  Returns fn's result."""
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    COMPILE_STATS.record(site, key, t0, time.perf_counter() - t0,
-                         n_args=n_args)
+def _signature(args) -> tuple:
+    import jax
+    return tuple((getattr(x, "shape", None), str(getattr(x, "dtype", "")))
+                 for x in jax.tree_util.tree_leaves(args))
+
+
+def call(site: str, key: str, fn, *args):
+    """Call the jitted ``fn``; its first call with an argument signature
+    builds the program and runs inside :meth:`CompileStats.build`.  Holds
+    no reference that keeps ``fn`` alive."""
+    sig = _signature(args)
+    with _called_lock:
+        seen = sig in _CALLED.get(fn, ())
+    if seen:
+        return fn(*args)
+    with COMPILE_STATS.build(site, key, n_args=len(sig)):
+        out = fn(*args)
+    with _called_lock:
+        _CALLED.setdefault(fn, set()).add(sig)
     return out

@@ -45,7 +45,7 @@ import time
 
 # safe one-way dependency: trace.py imports this module only lazily
 # (inside get_tracer), never at module load
-from superlu_dist_tpu.obs.trace import NULL_SPAN
+from superlu_dist_tpu.obs.trace import NULL_SPAN, ProfilerSpan
 from superlu_dist_tpu.utils.lockwatch import make_lock
 
 
@@ -159,8 +159,11 @@ class FlightRecorder:
             stack.pop()
 
     # ---- tracer protocol ------------------------------------------------
-    def span(self, name, cat="phase", **attrs):
+    def _open(self, name, cat, attrs):
         return _FlightSpan(self, name, cat, attrs)
+
+    def span(self, name, cat="phase", **attrs):
+        return ProfilerSpan(name, cat, attrs, self._open(name, cat, attrs))
 
     def complete(self, name, cat, t0, dur, **attrs):
         """t0: time.perf_counter() seconds; dur: seconds (the

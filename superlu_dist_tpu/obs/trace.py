@@ -24,11 +24,23 @@ Artifacts (env-gated by ``SLU_TPU_TRACE=<path>``):
 (parallel/pgssvx.py ranks) can share one env var without clobbering
 each other's artifacts.
 
+The profiler sink: every ``span(name, cat, **attrs)``, whatever tracer
+is active, also opens a ``jax.profiler.TraceAnnotation`` named
+``slu.<cat>.<name>`` with ``attrs`` as its metadata — a TraceMe host
+event in the JAX profiler's own trace, on the device trace's clock, so a
+``jax.profiler.trace`` of a run shows the program's phases, builds, rungs
+and solves beside the device ops.  With no profiler running a TraceMe
+records nothing and costs about a microsecond; it never blocks and never
+implies ``profiling``.  Names come from a small fixed vocabulary (phase
+names, compile sites, rung names, ``device-solve``); variable data goes
+into the attributes.  ``complete()`` records (already timed) reach only
+the file tracer and the flight recorder.
+
 Disabled path (env unset): ``get_tracer()`` returns the module-level
-``NULL_TRACER`` singleton whose ``span()`` hands back one reused no-op
-span object — no file is opened, no string is formatted, no timestamp
-is read.  Hot loops additionally guard on ``tracer.enabled`` so even
-the attribute-dict construction is skipped when tracing is off.
+``NULL_TRACER`` singleton whose spans feed the profiler sink alone — no
+file is opened, no string is formatted, no timestamp is read.  Hot loops
+additionally guard on ``tracer.enabled`` so ``complete()`` records and
+their attribute dicts are skipped when tracing is off.
 """
 
 from __future__ import annotations
@@ -51,9 +63,11 @@ import time
 #: serve/server.py and serve/fleet.py): one enclosing span per ticket
 #: with nested per-stage children (queue_wait / coalesce / dispatch /
 #: device / refine / deliver), all tagged with the ticket's trace_id so
-#: scripts/trace_merge.py can join a ticket across processes.
+#: scripts/trace_merge.py can join a ticket across processes.  "rung"
+#: spans come from the escalation ladder (drivers/gssvx._escalate): one
+#: per rung, covering its refactor, solver and refinement.
 CATEGORIES = ("phase", "dispatch", "kernel", "comm", "host-offload",
-              "verify", "compile", "request")
+              "verify", "compile", "request", "rung")
 
 
 class _NullSpan:
@@ -73,9 +87,51 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_TraceAnnotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first span (the
+    package's light imports do not pull jax in)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+class ProfilerSpan:
+    """One span on the profiler's clock — a TraceMe named
+    ``slu.<cat>.<name>`` — teed to a sink span (the file tracer's, the
+    flight recorder's, or the no-op one)."""
+
+    __slots__ = ("_ann", "_sink")
+
+    def __init__(self, name, cat, attrs, sink=NULL_SPAN):
+        self._ann = (_TraceAnnotation or _trace_annotation())(
+            f"slu.{cat}.{name}", **attrs)
+        self._sink = sink
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._sink.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._sink.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        """Attach attributes discovered mid-span to both sinks."""
+        self._ann.set_metadata(**attrs)
+        self._sink.set(**attrs)
+        return self
+
 
 class NullTracer:
-    """Disabled tracer: every operation is a constant-time no-op."""
+    """Disabled tracer: spans feed only the profiler sink; every other
+    operation is a constant-time no-op."""
 
     __slots__ = ()
     enabled = False
@@ -83,7 +139,7 @@ class NullTracer:
     path = None
 
     def span(self, name, cat="phase", **attrs):
-        return NULL_SPAN
+        return ProfilerSpan(name, cat, attrs)
 
     def complete(self, name, cat, t0, dur, **attrs):
         pass
@@ -190,11 +246,14 @@ class Tracer:
             self._jsonl.write(json.dumps(ev, default=str) + "\n")
 
     # ---- public API ----------------------------------------------------
+    def _open(self, name, cat, attrs):
+        return _Span(self, name, cat, attrs)
+
     def span(self, name, cat="phase", **attrs):
         """Context manager timing a nested span.  ``attrs`` should be
         plain scalars (ints/floats/short strings) — they land in the
-        record's ``args``."""
-        return _Span(self, name, cat, attrs)
+        record's ``args`` and the profiler event's metadata."""
+        return ProfilerSpan(name, cat, attrs, self._open(name, cat, attrs))
 
     def complete(self, name, cat, t0, dur, **attrs):
         """Record an already-timed span: ``t0`` is a ``time.perf_counter()``
@@ -281,7 +340,8 @@ class TeeTracer:
         return any(getattr(t, "profiling", False) for t in self._tracers)
 
     def span(self, name, cat="phase", **attrs):
-        return _TeeSpan([t.span(name, cat, **attrs) for t in self._tracers])
+        return ProfilerSpan(name, cat, attrs, _TeeSpan(
+            [t._open(name, cat, attrs) for t in self._tracers]))
 
     def complete(self, name, cat, t0, dur, **attrs):
         for t in self._tracers:

@@ -179,12 +179,12 @@ class DeviceSpMV:
         n = self.n_rows
 
         @jax.jit
-        def spmv(vals, rows, cols, x):
+        def refine_spmv(vals, rows, cols, x):
             contrib = vals[:, None] * x[cols]
             y = jnp.zeros((n, x.shape[1]), dtype=contrib.dtype)
             return y.at[rows].add(contrib)
 
-        self._fn = spmv
+        self._fn = refine_spmv
 
     @property
     def nnz(self) -> int:
@@ -195,8 +195,11 @@ class DeviceSpMV:
         x = np.asarray(x)
         squeeze = x.ndim == 1
         x2 = x[:, None] if squeeze else x
-        y = np.asarray(self._fn(vals, self._rows, self._cols,
-                                jnp.asarray(x2)))
+        # a new matrix is a new program: its first call is a census build
+        from superlu_dist_tpu.obs.compilestats import call
+        y = np.asarray(call("spmv", f"refine_spmv n{self.n_rows} "
+                            f"nnz{self._nnz} k{x2.shape[1]}", self._fn,
+                            vals, self._rows, self._cols, jnp.asarray(x2)))
         return y[:, 0] if squeeze else y
 
     def matvec(self, x: np.ndarray) -> np.ndarray:

@@ -245,7 +245,6 @@ class SpmdFactorExecutor:
         self.offload = 0.0
         self.granularity = "group"
         self.n_kernels = len(self._programs)
-        self.last_profile = None
         self.last_dispatch_seconds = 0.0
 
     def _label(self, key) -> str:
@@ -274,18 +273,20 @@ class SpmdFactorExecutor:
                 todo[key] = (avals, pool, thresh, *args)
         if not todo:
             return
-        keys, lowered, starts = list(todo), [], []
+        keys, lowered, t_lower = list(todo), [], []
         for key in keys:
             fn, args = self._programs[key], todo[key]
             maybe_audit(self._census_site, self._label(key), fn, args,
                         dead=(1,), mesh_axes=self._axes)
-            starts.append(time.perf_counter())
+            t0 = time.perf_counter()
             lowered.append(fn.lower(*args))
-        for i, exe, secs in compile_all(lowered,
-                                        label=lambda i: self._label(keys[i])):
+            t_lower.append(time.perf_counter() - t0)
+        for i, exe, _ in compile_all(
+                lowered, label=lambda i: self._label(keys[i]),
+                build=lambda i: COMPILE_STATS.build(
+                    self._census_site, self._label(keys[i]),
+                    n_args=len(todo[keys[i]]), before=t_lower[i])):
             self._compiled[keys[i]] = exe
-            COMPILE_STATS.record(self._census_site, self._label(keys[i]),
-                                 starts[i], secs, n_args=len(todo[keys[i]]))
 
     def __call__(self, avals, thresh):
         tracer = get_tracer()

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from superlu_dist_tpu.numeric.factor import NumericFactorization
-from superlu_dist_tpu.obs.compilestats import COMPILE_STATS
+from superlu_dist_tpu.obs.compilestats import call
 from superlu_dist_tpu.obs.trace import get_tracer
 from superlu_dist_tpu.solve.plan import SolvePlan, build_solve_plan, chunk_nrhs
 
@@ -56,17 +56,6 @@ def _audit_sweep(label: str, kern, args, dead) -> None:
     by every kernel factory above, which is what SLU111 verifies."""
     from superlu_dist_tpu.utils.programaudit import maybe_audit
     maybe_audit("solve.device", label, kern, args, dead=dead)
-
-
-def _sweep_kernel_builds() -> int:
-    """Total jitted-closure builds across the solve kernel factories —
-    the compile-census marker for one solve's sweeps (a fresh closure's
-    first invocation compiles synchronously inside the sweep)."""
-    return (_fwd_kernel.cache_info().misses
-            + _bwd_kernel.cache_info().misses
-            + _fwd_trans_kernel.cache_info().misses
-            + _bwd_trans_kernel.cache_info().misses
-            + _diag_inv_kernel.cache_info().misses)
 
 
 def _trsm(a, b, lower, unit, trans, leaf, prec="highest"):
@@ -208,44 +197,49 @@ def _bwd_body_trans(lpanel, x, first, rows, ws, w, u, n, conj, leaf,
     return x.at[cols].set(y, mode="drop")
 
 
+# Program names (the ``jit_<name>`` module a device trace reports): the
+# forward and backward sweeps are ``solve_fwd`` / ``solve_bwd`` whether
+# fused or per batch, the transpose pair ``solve_fwd_trans`` /
+# ``solve_bwd_trans``.
+
 @functools.lru_cache(maxsize=None)
 def _fwd_kernel(batch, m, w, u, nrhs, n, dtype, use_inv=False, leaf=0,
                 prec="highest"):
-    def step(lpanel, x, lsum, first, rows, ws, linv=None):
+    def solve_fwd(lpanel, x, lsum, first, rows, ws, linv=None):
         return _fwd_body(lpanel, x, lsum, first, rows, ws, w, u, n,
                          use_inv, linv, leaf, prec)
 
-    return jax.jit(step, donate_argnums=(1, 2))
+    return jax.jit(solve_fwd, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=None)
 def _bwd_kernel(batch, m, w, u, nrhs, n, dtype, use_inv=False, leaf=0,
                 prec="highest"):
-    def step(lpanel, upanel, x, first, rows, ws, uinv=None):
+    def solve_bwd(lpanel, upanel, x, first, rows, ws, uinv=None):
         return _bwd_body(lpanel, upanel, x, first, rows, ws, w, u, n,
                          use_inv, uinv, leaf, prec)
 
-    return jax.jit(step, donate_argnums=(2,))
+    return jax.jit(solve_bwd, donate_argnums=(2,))
 
 
 @functools.lru_cache(maxsize=None)
 def _fwd_trans_kernel(batch, m, w, u, nrhs, n, dtype, conj=False, leaf=0,
                       prec="highest"):
-    def step(lpanel, upanel, x, lsum, first, rows, ws):
+    def solve_fwd_trans(lpanel, upanel, x, lsum, first, rows, ws):
         return _fwd_body_trans(lpanel, upanel, x, lsum, first, rows, ws,
                                w, u, n, conj, leaf, prec)
 
-    return jax.jit(step, donate_argnums=(2, 3))
+    return jax.jit(solve_fwd_trans, donate_argnums=(2, 3))
 
 
 @functools.lru_cache(maxsize=None)
 def _bwd_trans_kernel(batch, m, w, u, nrhs, n, dtype, conj=False, leaf=0,
                       prec="highest"):
-    def step(lpanel, x, first, rows, ws):
+    def solve_bwd_trans(lpanel, x, first, rows, ws):
         return _bwd_body_trans(lpanel, x, first, rows, ws, w, u, n, conj,
                                leaf, prec)
 
-    return jax.jit(step, donate_argnums=(1,))
+    return jax.jit(solve_bwd_trans, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -253,7 +247,7 @@ def _diag_inv_kernel(w, dtype, leaf=0, prec="highest"):
     """Batched inverses of the packed diagonal blocks — the
     pdCompute_Diag_Inv analog (SRC/pdgstrs.c:647, dtrtri per block)."""
 
-    def inv(lpanel):
+    def solve_diag_inv(lpanel):
         f11 = lpanel[:, :w, :w]
         eye = jnp.broadcast_to(jnp.eye(w, dtype=lpanel.dtype),
                                f11.shape)
@@ -263,7 +257,7 @@ def _diag_inv_kernel(w, dtype, leaf=0, prec="highest"):
                      leaf=leaf, prec=prec)
         return linv, uinv
 
-    return jax.jit(inv)
+    return jax.jit(solve_diag_inv)
 
 
 def _pad_panels(lp, up, w0, u0, W, U):
@@ -436,9 +430,11 @@ class DeviceSolver:
         if self._invs_cached is None:
             if self.diag_inv:
                 self._invs_cached = [
-                    _diag_inv_kernel(grp.w, str(jnp.dtype(self.fact.dtype)),
-                                     self.trsm_leaf,
-                                     self.gemm_prec)(jnp.asarray(lp))
+                    call("diag_inv", f"solve_diag_inv b{grp.batch} w{grp.w}",
+                         _diag_inv_kernel(grp.w,
+                                          str(jnp.dtype(self.fact.dtype)),
+                                          self.trsm_leaf, self.gemm_prec),
+                         jnp.asarray(lp))
                     for (grp, _, _, _), (lp, _) in zip(self._groups,
                                                        self.fronts)]
             else:
@@ -458,14 +454,14 @@ class DeviceSolver:
         prec = self.gemm_prec
         meta = [(grp.w, grp.u) for grp, _, _, _ in self._groups]
 
-        def fwd(x, lsum, fronts, idx, invs):
+        def solve_fwd(x, lsum, fronts, idx, invs):
             for (w, u), (lp, _), (firsts, rows, ws), (linv, _) in zip(
                     meta, fronts, idx, invs):
                 x, lsum = _fwd_body(lp, x, lsum, firsts, rows, ws, w, u,
                                     n1, use_inv, linv, leaf, prec)
             return x, lsum
 
-        def bwd(x, fronts, idx, invs):
+        def solve_bwd(x, fronts, idx, invs):
             for (w, u), (lp, up), (firsts, rows, ws), (_, uinv) in zip(
                     reversed(meta), reversed(fronts), reversed(idx),
                     reversed(invs)):
@@ -473,8 +469,8 @@ class DeviceSolver:
                               use_inv, uinv, leaf, prec)
             return x
 
-        fns = (jax.jit(fwd, donate_argnums=(0, 1)),
-               jax.jit(bwd, donate_argnums=(0,)))
+        fns = (jax.jit(solve_fwd, donate_argnums=(0, 1)),
+               jax.jit(solve_bwd, donate_argnums=(0,)))
         self._fused_cache[kb] = fns
         return fns
 
@@ -487,22 +483,22 @@ class DeviceSolver:
         prec = self.gemm_prec
         meta = [(grp.w, grp.u) for grp, _, _, _ in self._groups]
 
-        def fwd(x, lsum, fronts, idx):
+        def solve_fwd_trans(x, lsum, fronts, idx):
             for (w, u), (lp, up), (firsts, rows, ws) in zip(
                     meta, fronts, idx):
                 x, lsum = _fwd_body_trans(lp, up, x, lsum, firsts, rows,
                                           ws, w, u, n1, conj, leaf, prec)
             return x, lsum
 
-        def bwd(x, fronts, idx):
+        def solve_bwd_trans(x, fronts, idx):
             for (w, u), (lp, _), (firsts, rows, ws) in zip(
                     reversed(meta), reversed(fronts), reversed(idx)):
                 x = _bwd_body_trans(lp, x, firsts, rows, ws, w, u, n1,
                                     conj, leaf, prec)
             return x
 
-        fns = (jax.jit(fwd, donate_argnums=(0, 1)),
-               jax.jit(bwd, donate_argnums=(0,)))
+        fns = (jax.jit(solve_fwd_trans, donate_argnums=(0, 1)),
+               jax.jit(solve_bwd_trans, donate_argnums=(0,)))
         self._fused_cache[("T", kb, conj)] = fns
         return fns
 
@@ -531,11 +527,6 @@ class DeviceSolver:
                                          4)}
         nonfinite_cols: list = []
         out = np.empty((self.n, k), dtype=dt)
-        # compile census: new sweep-kernel closures (streamed lru misses
-        # or fresh fused programs) mean this call compiles — time the
-        # sweep issue and account it per (n, nrhs-bucket, mode)
-        builds0 = _sweep_kernel_builds() + len(self._fused_cache)
-        t0_build = time.perf_counter()
         d2h_s, d2h_bytes = 0.0, 0
         with tracer.span("device-solve", cat="kernel", n=self.n, nrhs=k,
                          padded_nrhs=kb_total, chunks=len(chunks),
@@ -590,15 +581,6 @@ class DeviceSolver:
                     nonfinite_cols.extend(
                         int(lo + j) for j in np.nonzero(~fin)[0])
                 out[:, lo:hi] = res
-            builds = (_sweep_kernel_builds() + len(self._fused_cache)
-                      - builds0)
-            if builds:
-                COMPILE_STATS.record(
-                    "solve.device",
-                    f"solve n{self.n} nrhs{kb_total} "
-                    f"{'fused' if self.fused else 'stream'}",
-                    t0_build, time.perf_counter() - t0_build,
-                    n_args=6, builds=builds)
             if tracer.enabled:
                 # the solution's D2H pull (the only factor-sized data
                 # that ever crosses the boundary per solve)
@@ -631,32 +613,36 @@ class DeviceSolver:
                        for _, firsts, rows, ws in self._groups]
                 _audit_sweep(f"fusedT-fwd n{self.n} k{kb}", fwd,
                              (x, lsum, self.fronts, idx), dead=(0, 1))
-                x, lsum = fwd(x, lsum, self.fronts, idx)
+                x, lsum = call("solve", f"solve_fwd_trans n{self.n} k{kb}",
+                               fwd, x, lsum, self.fronts, idx)
                 _audit_sweep(f"fusedT-bwd n{self.n} k{kb}", bwd,
                              (x, self.fronts, idx), dead=(0,))
-                return bwd(x, self.fronts, idx)
+                return call("solve", f"solve_bwd_trans n{self.n} k{kb}",
+                            bwd, x, self.fronts, idx)
             # Uᵀ forward, sweep batches ascending
             for (grp, firsts, rows, ws), (lp, up) in zip(
                     self._groups, self.fronts):
                 kern = _fwd_trans_kernel(grp.batch, grp.m, grp.w, grp.u,
                                          kb, n1, str(dt), conj, leaf,
                                          self.gemm_prec)
-                _audit_sweep(
-                    f"fwdT b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
-                    f"k{kb} n{self.n}", kern,
-                    (lp, up, x, lsum, firsts, rows, ws), dead=(2, 3))
-                x, lsum = kern(lp, up, x, lsum, firsts, rows, ws)
+                label = (f"b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
+                         f"k{kb} n{self.n}")
+                _audit_sweep(f"fwdT {label}", kern,
+                             (lp, up, x, lsum, firsts, rows, ws), dead=(2, 3))
+                x, lsum = call("solve", f"solve_fwd_trans {label}", kern,
+                               lp, up, x, lsum, firsts, rows, ws)
             # Lᵀ backward, descending
             for (grp, firsts, rows, ws), (lp, up) in zip(
                     reversed(self._groups), reversed(self.fronts)):
                 kern = _bwd_trans_kernel(grp.batch, grp.m, grp.w, grp.u,
                                          kb, n1, str(dt), conj, leaf,
                                          self.gemm_prec)
-                _audit_sweep(
-                    f"bwdT b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
-                    f"k{kb} n{self.n}", kern,
-                    (lp, x, firsts, rows, ws), dead=(1,))
-                x = kern(lp, x, firsts, rows, ws)
+                label = (f"b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
+                         f"k{kb} n{self.n}")
+                _audit_sweep(f"bwdT {label}", kern,
+                             (lp, x, firsts, rows, ws), dead=(1,))
+                x = call("solve", f"solve_bwd_trans {label}", kern,
+                         lp, x, firsts, rows, ws)
             return x
 
         return self._run_sweeps(rhs, sweeps)
@@ -674,13 +660,15 @@ class DeviceSolver:
                 fwd, bwd = self._fused_fns(kb)
                 idx = [(firsts, rows, ws)
                        for _, firsts, rows, ws in self._groups]
+                invs = self._invs
                 _audit_sweep(f"fused-fwd n{self.n} k{kb}", fwd,
-                             (x, lsum, self.fronts, idx, self._invs),
-                             dead=(0, 1))
-                x, lsum = fwd(x, lsum, self.fronts, idx, self._invs)
+                             (x, lsum, self.fronts, idx, invs), dead=(0, 1))
+                x, lsum = call("solve", f"solve_fwd n{self.n} k{kb}", fwd,
+                               x, lsum, self.fronts, idx, invs)
                 _audit_sweep(f"fused-bwd n{self.n} k{kb}", bwd,
-                             (x, self.fronts, idx, self._invs), dead=(0,))
-                return bwd(x, self.fronts, idx, self._invs)
+                             (x, self.fronts, idx, invs), dead=(0,))
+                return call("solve", f"solve_bwd n{self.n} k{kb}", bwd,
+                            x, self.fronts, idx, invs)
             # forward in dispatch order (topological: every descendant's
             # batch precedes its ancestors' under either scheduler)
             for (grp, firsts, rows, ws), (lp, up), (linv, _) in zip(
@@ -689,23 +677,22 @@ class DeviceSolver:
                                    str(dt), use_inv, leaf, self.gemm_prec)
                 args = ((lp, x, lsum, firsts, rows, ws, linv) if use_inv
                         else (lp, x, lsum, firsts, rows, ws))
-                _audit_sweep(
-                    f"fwd b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
-                    f"k{kb} n{self.n}", kern, args, dead=(1, 2))
-                x, lsum = kern(*args)
+                label = (f"b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
+                         f"k{kb} n{self.n}")
+                _audit_sweep(f"fwd {label}", kern, args, dead=(1, 2))
+                x, lsum = call("solve", f"solve_fwd {label}", kern, *args)
             # backward, descending
             for (grp, firsts, rows, ws), (lp, up), (_, uinv) in zip(
                     reversed(self._groups), reversed(self.fronts),
                     reversed(self._invs)):
                 kern = _bwd_kernel(grp.batch, grp.m, grp.w, grp.u, kb, n1,
                                    str(dt), use_inv, leaf, self.gemm_prec)
-                _audit_sweep(
-                    f"bwd b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
-                    f"k{kb} n{self.n}", kern,
-                    (lp, up, x, firsts, rows, ws, uinv) if use_inv
-                    else (lp, up, x, firsts, rows, ws), dead=(2,))
-                x = (kern(lp, up, x, firsts, rows, ws, uinv) if use_inv
-                     else kern(lp, up, x, firsts, rows, ws))
+                args = ((lp, up, x, firsts, rows, ws, uinv) if use_inv
+                        else (lp, up, x, firsts, rows, ws))
+                label = (f"b{grp.batch} m{grp.m} w{grp.w} u{grp.u} "
+                         f"k{kb} n{self.n}")
+                _audit_sweep(f"bwd {label}", kern, args, dead=(2,))
+                x = call("solve", f"solve_bwd {label}", kern, *args)
             return x
 
         return self._run_sweeps(rhs, sweeps)

@@ -343,8 +343,6 @@ def _register_all() -> None:
       group="obs")
     r("SLU_TPU_STATS", "flag", False,
       "print the PStatPrint-analog report from any driver run", group="obs")
-    r("SLU_TPU_PROFILE", "flag", False,
-      "deprecated legacy '# lvl=' stderr kernel trace", group="obs")
     r("SLU_TPU_PROGRESS", "int", 0,
       "log every K groups/levels issued (0=silent)", group="obs")
     r("SLU_TPU_PEAK_GFLOPS", "float", 0.0,
@@ -543,22 +541,6 @@ def env_flag(name: str, default=_UNSET) -> bool:
     if raw is None:
         return bool(d)
     return raw.strip().lower() not in _FLAG_FALSE
-
-
-_deprecation_warned: set = set()
-
-
-def deprecated_knob_warning(name: str, hint: str) -> None:
-    """One-shot ``DeprecationWarning`` for a deprecated-but-still-honored
-    knob (at most once per process per knob, and only when the knob is
-    actually set in the environment) — the knob's OUTPUT stays unchanged
-    so downstream parsers (scripts/mfu_report.py) keep working."""
-    if name in _deprecation_warned or os.environ.get(name) is None:
-        return
-    _deprecation_warned.add(name)
-    import warnings
-    warnings.warn(f"{name} is deprecated: {hint}",
-                  DeprecationWarning, stacklevel=3)
 
 
 def knob_table_md(groups: tuple | None = None) -> str:

@@ -392,7 +392,7 @@ def test_warm_compile_cache_prebake(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_census_pending_keys_name_uncompiled_buckets():
-    """Executors announce their full expected kernel set; record()
+    """Executors announce their full expected kernel set; build()
     retires keys as they build — the delta is the `pending_kernels`
     list a factor-compile watchdog row emits so the postmortem names
     the offenders (the BENCH_r02 gap)."""
@@ -404,13 +404,14 @@ def test_census_pending_keys_name_uncompiled_buckets():
     cs.announce("mega._kernel", ["lu b4 m64 w32 u32", "lu b8 m96 w64 u32"])
     assert {p["key"] for p in cs.pending()} == {"lu b4 m64 w32 u32",
                                                 "lu b8 m96 w64 u32"}
-    t0 = time.perf_counter()
-    cs.record("mega._kernel", "lu b4 m64 w32 u32", t0, 0.1)
+    with cs.build("mega._kernel", "lu b4 m64 w32 u32"):
+        pass
     assert [p["key"] for p in cs.pending()] == ["lu b8 m96 w64 u32"]
     # a built key is never re-announced (warmed executor, same plan)
     cs.announce("mega._kernel", ["lu b4 m64 w32 u32"])
     assert [p["key"] for p in cs.pending()] == ["lu b8 m96 w64 u32"]
-    cs.record("mega._kernel", "lu b8 m96 w64 u32", t0, 0.1)
+    with cs.build("mega._kernel", "lu b8 m96 w64 u32"):
+        pass
     assert cs.pending() == []
 
 

@@ -123,8 +123,8 @@ def test_disabled_path_is_noop(tmp_path, monkeypatch):
     t = trace.get_tracer()
     assert t is trace.NULL_TRACER
     assert not t.enabled
-    # one reused no-op span object, regardless of args
-    assert t.span("a") is t.span("b", cat="kernel", x=1)
+    # spans reach the profiler sink only: no file or ring behind them
+    assert t.span("b", cat="kernel", x=1)._sink is trace.NULL_SPAN
     with t.span("a") as sp:
         sp.set(ignored=True)
     t.complete("x", "comm", 0.0, 1.0)
@@ -132,7 +132,8 @@ def test_disabled_path_is_noop(tmp_path, monkeypatch):
     t.close()
     assert os.listdir(tmp_path) == []        # nothing written, ever
     # near-zero overhead: a hundred thousand disabled spans in well under
-    # a second (they allocate nothing and read no clock)
+    # a second (one TraceMe each, which records nothing with no profiler
+    # running, and no clock read)
     t0 = time.perf_counter()
     for _ in range(100_000):
         with t.span("hot", cat="kernel"):
@@ -212,9 +213,6 @@ def test_stream_executor_kernel_spans(tmp_path):
             assert key in args, (key, args)
         assert args["executed_flops"] >= args["structural_flops"] > 0
         assert args["padding"] >= 1.0
-    # tracing implies the profile record too (no stderr scraping needed,
-    # but the legacy consumer keeps working)
-    assert len(ex.last_profile) == len(plan.groups)
 
 
 def test_fused_executor_kernel_span(tmp_path):
@@ -561,7 +559,8 @@ def test_compile_census_cold_then_warm_stream(tmp_path):
     spans = [e for e in events if e["cat"] == "compile"]
     assert len(spans) == cold
     for e in spans:
-        assert e["name"] == "compile stream._kernel"
+        assert e["name"] == "stream"
+        assert e["args"]["site"] == "stream._kernel"
         assert "key" in e["args"]
 
 

@@ -410,13 +410,12 @@ def test_clean_program_memoized_with_census_note(fresh_sharding_auditor):
 
 
 def test_census_rows_carry_the_memory_column(fresh_sharding_auditor):
-    import time
     from superlu_dist_tpu.obs.compilestats import COMPILE_STATS
     fn, args = _fixture_build("mem_bounded")
     stats = programaudit.maybe_audit("test.site", "colkey", fn, args)
     mark = COMPILE_STATS.marker()
-    t0 = time.perf_counter()
-    COMPILE_STATS.record("test.site", "colkey", t0, 0.01)
+    with COMPILE_STATS.build("test.site", "colkey"):
+        pass
     rows = [r for r in COMPILE_STATS.census(since=mark)
             if r["key"] == "colkey"]
     assert rows and rows[0]["peak_bytes_est"] == stats["peak_bytes_est"]
