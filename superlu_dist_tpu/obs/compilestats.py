@@ -32,7 +32,10 @@ Consumers: the ``compile`` trace category
 report (utils/stats.py via drivers/gssvx.factorize_numeric), the
 ``compile_seconds`` / ``compile_census`` fields of the bench JSON row,
 flight-recorder postmortems (obs/flightrec.py), and
-``scripts/compile_census.py``.
+``scripts/compile_census.py``.  :func:`tally` counts the program calls
+one thread makes through :func:`call` as hits (a built program reused)
+and misses (a build): the ``device-solve`` span's ``program_hits`` /
+``program_misses``.
 """
 
 from __future__ import annotations
@@ -320,6 +323,32 @@ def _signature(args) -> tuple:
                  for x in jax.tree_util.tree_leaves(args))
 
 
+class Tally:
+    """Program calls counted by :func:`tally`: ``hits`` reused a built
+    program, ``misses`` built one."""
+
+    __slots__ = ("hits", "misses")
+
+    def __init__(self):
+        self.hits = self.misses = 0
+
+
+#: the innermost open :func:`tally` of each thread
+_tallies = threading.local()
+
+
+@contextlib.contextmanager
+def tally():
+    """Count the :func:`call` invocations this thread makes in the
+    body."""
+    t, prev = Tally(), getattr(_tallies, "open", None)
+    _tallies.open = t
+    try:
+        yield t
+    finally:
+        _tallies.open = prev
+
+
 def call(site: str, key: str, fn, *args):
     """Call the jitted ``fn``; its first call with an argument signature
     builds the program and runs inside :meth:`CompileStats.build`.  Holds
@@ -327,8 +356,13 @@ def call(site: str, key: str, fn, *args):
     sig = _signature(args)
     with _called_lock:
         seen = sig in _CALLED.get(fn, ())
+    t = getattr(_tallies, "open", None)
     if seen:
+        if t is not None:
+            t.hits += 1
         return fn(*args)
+    if t is not None:
+        t.misses += 1
     with COMPILE_STATS.build(site, key, n_args=len(sig)):
         out = fn(*args)
     with _called_lock:

@@ -30,6 +30,7 @@ fallback (parallel/pgssvx.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -138,6 +139,22 @@ def gather_rows(parts: list[DistributedCSR]) -> SparseCSR:
                      np.concatenate(indices), np.concatenate(data))
 
 
+@functools.lru_cache(maxsize=16)
+def _refine_spmv(n):
+    """y = A·x over a resident pattern of ``n`` rows, the values, pattern
+    and x as arguments: every operator with ``n`` rows shares the one
+    program, so a refactor's new matrix does not rebuild it."""
+    import jax
+    import jax.numpy as jnp
+
+    def refine_spmv(vals, rows, cols, x):
+        contrib = vals[:, None] * x[cols]
+        y = jnp.zeros((n, x.shape[1]), dtype=contrib.dtype)
+        return y.at[rows].add(contrib)
+
+    return jax.jit(refine_spmv)
+
+
 class DeviceSpMV:
     """Single-device y = A·x with the pattern resident in HBM — the
     pdgsmv analog (SRC/pdgsmv.c:234) used by iterative refinement when
@@ -176,15 +193,7 @@ class DeviceSpMV:
         self._avals = jnp.asarray(np.abs(a.data).astype(
             dtype if not np.issubdtype(dtype, np.complexfloating)
             else np.dtype(dtype).type(0).real.dtype))
-        n = self.n_rows
-
-        @jax.jit
-        def refine_spmv(vals, rows, cols, x):
-            contrib = vals[:, None] * x[cols]
-            y = jnp.zeros((n, x.shape[1]), dtype=contrib.dtype)
-            return y.at[rows].add(contrib)
-
-        self._fn = refine_spmv
+        self._fn = _refine_spmv(self.n_rows)
 
     @property
     def nnz(self) -> int:
@@ -195,7 +204,7 @@ class DeviceSpMV:
         x = np.asarray(x)
         squeeze = x.ndim == 1
         x2 = x[:, None] if squeeze else x
-        # a new matrix is a new program: its first call is a census build
+        # a new size, x width or dtype is a build: a census record
         from superlu_dist_tpu.obs.compilestats import call
         y = np.asarray(call("spmv", f"refine_spmv n{self.n_rows} "
                             f"nnz{self._nnz} k{x2.shape[1]}", self._fn,

@@ -360,6 +360,9 @@ class SpmdSolver(DeviceSolver):
                          schedule=schedule, window=window, align=align,
                          trsm_leaf=trsm_leaf, nrhs_max=nrhs_max,
                          nrhs_growth=nrhs_growth, gemm_prec=gemm_prec)
+        # the shard_map programs capture this mesh and these panels'
+        # layout: one set per solver
+        self._fused_cache = {}
         self.spmd_mesh = mesh
         self._axes = tuple(mesh.axis_names)
         self.nd = nd = int(np.prod(mesh.devices.shape))

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from superlu_dist_tpu.numeric.factor import NumericFactorization
-from superlu_dist_tpu.obs.compilestats import call
+from superlu_dist_tpu.obs.compilestats import call, tally
 from superlu_dist_tpu.obs.trace import get_tracer
 from superlu_dist_tpu.solve.plan import SolvePlan, build_solve_plan, chunk_nrhs
 
@@ -260,6 +260,56 @@ def _diag_inv_kernel(w, dtype, leaf=0, prec="highest"):
     return jax.jit(solve_diag_inv)
 
 
+# The fused sweeps: one program per sweep over every batch of a plan.
+# The key is everything the traced body reads that is not an argument —
+# the per-batch (w, u) in sweep order, n + 1 and the solver's settings —
+# so every solver of one plan (each refactor's, a ladder rung's, the
+# condition estimate's) shares one jitted program; panels, index maps
+# and the RHS are arguments, keyed by jit on shape and dtype.  Bounded:
+# each entry holds its compiled executables.
+_FUSED_PLANS = 64
+
+
+@functools.lru_cache(maxsize=_FUSED_PLANS)
+def _fused_kernels(meta, n, use_inv=False, leaf=0, prec="highest"):
+    def solve_fwd(x, lsum, fronts, idx, invs):
+        for (w, u), (lp, _), (firsts, rows, ws), (linv, _) in zip(
+                meta, fronts, idx, invs):
+            x, lsum = _fwd_body(lp, x, lsum, firsts, rows, ws, w, u, n,
+                                use_inv, linv, leaf, prec)
+        return x, lsum
+
+    def solve_bwd(x, fronts, idx, invs):
+        for (w, u), (lp, up), (firsts, rows, ws), (_, uinv) in zip(
+                reversed(meta), reversed(fronts), reversed(idx),
+                reversed(invs)):
+            x = _bwd_body(lp, up, x, firsts, rows, ws, w, u, n, use_inv,
+                          uinv, leaf, prec)
+        return x
+
+    return (jax.jit(solve_fwd, donate_argnums=(0, 1)),
+            jax.jit(solve_bwd, donate_argnums=(0,)))
+
+
+@functools.lru_cache(maxsize=_FUSED_PLANS)
+def _fused_trans_kernels(meta, n, conj=False, leaf=0, prec="highest"):
+    def solve_fwd_trans(x, lsum, fronts, idx):
+        for (w, u), (lp, up), (firsts, rows, ws) in zip(meta, fronts, idx):
+            x, lsum = _fwd_body_trans(lp, up, x, lsum, firsts, rows, ws,
+                                      w, u, n, conj, leaf, prec)
+        return x, lsum
+
+    def solve_bwd_trans(x, fronts, idx):
+        for (w, u), (lp, _), (firsts, rows, ws) in zip(
+                reversed(meta), reversed(fronts), reversed(idx)):
+            x = _bwd_body_trans(lp, x, firsts, rows, ws, w, u, n, conj,
+                                leaf, prec)
+        return x
+
+    return (jax.jit(solve_fwd_trans, donate_argnums=(0, 1)),
+            jax.jit(solve_bwd_trans, donate_argnums=(0,)))
+
+
 def _pad_panels(lp, up, w0, u0, W, U):
     """Promote one factor group's panel stack from its (w0, u0) padding
     to a merged solve key (W, U): identity on the new pivot diagonal
@@ -348,7 +398,6 @@ class DeviceSolver:
         if fused == "auto":
             fused = len(solve_plan.groups) <= 256
         self.fused = bool(fused)
-        self._fused_cache = {}
         self._replicate = None
         sf = plan.sf
         self.n = plan.n
@@ -388,6 +437,8 @@ class DeviceSolver:
                 rows[slot, :len(r)] = r
             self._groups.append((sg, firsts, _put(rows), _put(sg.ws)))
         self.fronts = panels
+        # the fused sweeps' static key: each batch's (w, u), sweep order
+        self._meta = tuple((grp.w, grp.u) for grp, _, _, _ in self._groups)
 
     @staticmethod
     def _gather_panels(sg, src_fronts, plan):
@@ -441,66 +492,16 @@ class DeviceSolver:
                 self._invs_cached = [(None, None)] * len(self._groups)
         return self._invs_cached
 
-    def _fused_fns(self, kb):
-        """One jitted program per sweep (all batches) for this nrhs
-        bucket.  (jit re-traces on shape/dtype changes anyway; the kb key
-        just avoids rebuilding the Python closures.)"""
-        fns = self._fused_cache.get(kb)
-        if fns is not None:
-            return fns
-        n1 = self.n + 1
-        use_inv = self.diag_inv
-        leaf = self.trsm_leaf
-        prec = self.gemm_prec
-        meta = [(grp.w, grp.u) for grp, _, _, _ in self._groups]
+    def _fused_fns(self):
+        """The fused forward and backward sweep programs of this
+        solver's plan and settings (:func:`_fused_kernels`)."""
+        return _fused_kernels(self._meta, self.n + 1, self.diag_inv,
+                              self.trsm_leaf, self.gemm_prec)
 
-        def solve_fwd(x, lsum, fronts, idx, invs):
-            for (w, u), (lp, _), (firsts, rows, ws), (linv, _) in zip(
-                    meta, fronts, idx, invs):
-                x, lsum = _fwd_body(lp, x, lsum, firsts, rows, ws, w, u,
-                                    n1, use_inv, linv, leaf, prec)
-            return x, lsum
-
-        def solve_bwd(x, fronts, idx, invs):
-            for (w, u), (lp, up), (firsts, rows, ws), (_, uinv) in zip(
-                    reversed(meta), reversed(fronts), reversed(idx),
-                    reversed(invs)):
-                x = _bwd_body(lp, up, x, firsts, rows, ws, w, u, n1,
-                              use_inv, uinv, leaf, prec)
-            return x
-
-        fns = (jax.jit(solve_fwd, donate_argnums=(0, 1)),
-               jax.jit(solve_bwd, donate_argnums=(0,)))
-        self._fused_cache[kb] = fns
-        return fns
-
-    def _fused_trans_fns(self, kb, conj):
-        fns = self._fused_cache.get(("T", kb, conj))
-        if fns is not None:
-            return fns
-        n1 = self.n + 1
-        leaf = self.trsm_leaf
-        prec = self.gemm_prec
-        meta = [(grp.w, grp.u) for grp, _, _, _ in self._groups]
-
-        def solve_fwd_trans(x, lsum, fronts, idx):
-            for (w, u), (lp, up), (firsts, rows, ws) in zip(
-                    meta, fronts, idx):
-                x, lsum = _fwd_body_trans(lp, up, x, lsum, firsts, rows,
-                                          ws, w, u, n1, conj, leaf, prec)
-            return x, lsum
-
-        def solve_bwd_trans(x, fronts, idx):
-            for (w, u), (lp, _), (firsts, rows, ws) in zip(
-                    reversed(meta), reversed(fronts), reversed(idx)):
-                x = _bwd_body_trans(lp, x, firsts, rows, ws, w, u, n1,
-                                    conj, leaf, prec)
-            return x
-
-        fns = (jax.jit(solve_fwd_trans, donate_argnums=(0, 1)),
-               jax.jit(solve_bwd_trans, donate_argnums=(0,)))
-        self._fused_cache[("T", kb, conj)] = fns
-        return fns
+    def _fused_trans_fns(self, conj):
+        """The fused transpose pair (:func:`_fused_trans_kernels`)."""
+        return _fused_trans_kernels(self._meta, self.n + 1, bool(conj),
+                                    self.trsm_leaf, self.gemm_prec)
 
     def _run_sweeps(self, rhs, sweeps):
         """Shared solve scaffolding: map the request's nrhs onto the
@@ -534,7 +535,7 @@ class DeviceSolver:
                          schedule=self.splan.schedule,
                          solve_flops=structural, executed_flops=executed,
                          padding_factor=stats["padding_factor"],
-                         dtype=str(dt)):
+                         dtype=str(dt)) as sp, tally() as programs:
             for lo, hi, kb in chunks:
                 pad = np.zeros((self.n + 1, kb), dtype=dt)
                 pad[:self.n, :hi - lo] = r2[:, lo:hi]
@@ -587,6 +588,9 @@ class DeviceSolver:
                 tracer.complete("solve-d2h", "comm",
                                 time.perf_counter() - d2h_s, d2h_s,
                                 op="d2h", bytes=d2h_bytes)
+            # whether this solve reused its sweep programs or built them
+            sp.set(program_hits=programs.hits,
+                   program_misses=programs.misses)
         stats["finite"] = not nonfinite_cols
         stats["nonfinite_cols"] = nonfinite_cols
         if nonfinite_cols and tracer.enabled:
@@ -608,7 +612,7 @@ class DeviceSolver:
 
         def sweeps(x, lsum, kb):
             if self.fused:
-                fwd, bwd = self._fused_trans_fns(kb, conj)
+                fwd, bwd = self._fused_trans_fns(conj)
                 idx = [(firsts, rows, ws)
                        for _, firsts, rows, ws in self._groups]
                 _audit_sweep(f"fusedT-fwd n{self.n} k{kb}", fwd,
@@ -657,7 +661,7 @@ class DeviceSolver:
 
         def sweeps(x, lsum, kb):
             if self.fused:
-                fwd, bwd = self._fused_fns(kb)
+                fwd, bwd = self._fused_fns()
                 idx = [(firsts, rows, ws)
                        for _, firsts, rows, ws in self._groups]
                 invs = self._invs

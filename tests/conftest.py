@@ -22,6 +22,19 @@ if "xla_force_host_platform_device_count" not in _flags:
 os.environ.setdefault("SLU_TPU_DRYRUN_BIG", "0")
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
+
+
+@pytest.fixture
+def fresh_solve_programs():
+    """Drop the process-wide solve-program caches (the fused sweeps and
+    the refinement SpMV), so that the test's first solver or operator
+    builds its programs whatever ran earlier in this process."""
+    from superlu_dist_tpu.parallel import dist
+    from superlu_dist_tpu.solve import device
+    for builder in (device._fused_kernels, device._fused_trans_kernels,
+                    dist._refine_spmv):
+        builder.cache_clear()

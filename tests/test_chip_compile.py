@@ -156,7 +156,7 @@ def test_device_solve_sweep_compiles_for_v5e(sweep, one_chip,
     plan = factors_n110592.plan
     solver = DeviceSolver(factors_n110592)
     assert solver.fused
-    fwd, bwd = solver._fused_fns(1)
+    fwd, bwd = solver._fused_fns()
     x = jax.ShapeDtypeStruct((plan.n + 1, 1), jnp.float32, sharding=one_chip)
     panels = _abstract(solver.fronts, one_chip)
     idx = _abstract([(f, r, w) for _, f, r, w in solver._groups], one_chip)

@@ -172,6 +172,65 @@ def test_fused_and_streamed_solve_agree():
     np.testing.assert_allclose(x_fused, want, rtol=1e-9, atol=1e-11)
 
 
+_PLAIN = ["solve_bwd", "solve_fwd"]
+_TRANS = ["solve_bwd_trans", "solve_fwd_trans"]
+
+
+@pytest.mark.parametrize("settings,builds", [
+    ({}, []),
+    ({"gemm_prec": "highest"}, _PLAIN + _TRANS),
+    ({"diag_inv": True}, _PLAIN),
+    ({"trsm_leaf": 8}, _PLAIN + _TRANS),
+    ({"conj": True}, _TRANS)],
+    ids=["same", "gemm_prec", "diag_inv", "trsm_leaf", "conj"])
+def test_refactor_reuses_sweep_programs(fresh_solve_programs, settings,
+                                        builds):
+    """A SamePattern refactor's new solver runs the fused sweeps the
+    first factorization's solver built: no ``solve`` build, each answer
+    its own factorization's.  A solver whose settings the traced sweeps
+    read (GEMM tier, DiagInv, TRSM leaf, conjugation) builds anew."""
+    from superlu_dist_tpu.obs.compilestats import COMPILE_STATS
+    from superlu_dist_tpu.solve.trisolve import lu_solve_trans
+    from superlu_dist_tpu.utils.options import Fact
+    a = poisson2d(9)
+    lu = _factor(a)
+    d = np.random.default_rng(8).standard_normal(a.n_rows)
+    first = lu.numeric
+    ds = DeviceSolver(first)
+    np.testing.assert_allclose(ds.solve(d), lu_solve(first, d),
+                               rtol=1e-9, atol=1e-11)
+    np.testing.assert_allclose(ds.solve_trans(d), lu_solve_trans(first, d),
+                               rtol=1e-9, atol=1e-11)
+    diag = np.repeat(np.arange(a.n_rows), np.diff(a.indptr)) == a.indices
+    a2 = type(a)(a.n_rows, a.n_cols, a.indptr, a.indices,
+                 a.data * np.where(diag, 1.5, 1.0))
+    x, lu2, stats, info = gssvx(
+        Options(fact=Fact.SamePattern_SameRowPerm,
+                iter_refine=IterRefine.NOREFINE), a2, d, lu=lu)
+    assert info == 0
+    second = lu2.numeric
+    assert second is not first and second.plan is first.plan
+    settings = dict(settings)
+    conj = settings.pop("conj", False)
+    ds2 = DeviceSolver(second, **settings)
+    m0 = COMPILE_STATS.marker()
+    got = ds2.solve(d)
+    got_t = ds2.solve_trans(d, conj=conj)
+    built = [r.key.split()[0] for r in COMPILE_STATS.records[m0:]
+             if r.site == "solve"]
+    assert sorted(built) == sorted(builds)
+    # a new setting is a new program, not a re-trace of the old one
+    assert ((ds2._fused_fns() is ds._fused_fns())
+            == ("solve_fwd" not in builds))
+    assert ((ds2._fused_trans_fns(conj) is ds._fused_trans_fns(False))
+            == ("solve_fwd_trans" not in builds))
+    np.testing.assert_allclose(got, lu_solve(second, d),
+                               rtol=1e-9, atol=1e-11)
+    np.testing.assert_allclose(got_t, lu_solve_trans(second, d, conj=conj),
+                               rtol=1e-9, atol=1e-11)
+    assert not np.allclose(got, lu_solve(first, d))
+
+
 @pytest.mark.parametrize("conj", [False, True])
 def test_device_solve_trans_matches_host(conj):
     """Device transpose sweeps (UT then LT, the trans_t path) vs the host

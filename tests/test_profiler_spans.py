@@ -77,10 +77,11 @@ def _inside(child, parent, same_line=True):
             and parent[2] <= child[2] and child[3] <= parent[3])
 
 
-def test_gssvx_spans_on_profiler_clock(tmp_path):
+def test_gssvx_spans_on_profiler_clock(tmp_path, fresh_solve_programs):
     """DOFACT then a FACTORED device solve, profiled: phase spans, the
     factor's and the sweeps' build spans and the device-solve span are
-    host events on the profiler's clock, properly nested."""
+    host events on the profiler's clock, properly nested.  A device solve
+    counts the programs it built as misses, those it reused as hits."""
     a = poisson3d(8)
     b = a.matvec(np.ones(a.n_rows))
 
@@ -121,6 +122,12 @@ def test_gssvx_spans_on_profiler_clock(tmp_path):
     for ev in solves:
         assert any(_inside(ev, p) for p in phases), ev
         assert ev[4]["nrhs"] == 1
+        built = [b for b in by["slu.compile.solve"] + by[
+            "slu.compile.diag_inv"] if _inside(b, ev)]
+        assert ev[4]["program_misses"] == len(built), ev
+        assert ev[4]["program_hits"] + len(built) >= 2, ev
+    assert solves[0][4]["program_misses"] > 0
+    assert solves[-1][4]["program_misses"] == 0
 
 
 def test_rung_spans_match_solve_report(tmp_path):
@@ -206,19 +213,19 @@ def test_solve_program_names(trans):
     x = jnp.zeros((plan.n + 1, 1), jnp.float64)
     idx = [(f, r, w) for _, f, r, w in solver._groups]
     if trans:
-        fwd, bwd = solver._fused_trans_fns(1, False)
+        fwd, bwd = solver._fused_trans_fns(False)
         names = (_module_name(fwd.lower(x, x, solver.fronts, idx)),
                  _module_name(bwd.lower(x, solver.fronts, idx)))
         assert names == ("jit_solve_fwd_trans", "jit_solve_bwd_trans")
     else:
-        fwd, bwd = solver._fused_fns(1)
+        fwd, bwd = solver._fused_fns()
         invs = solver._invs
         names = (_module_name(fwd.lower(x, x, solver.fronts, idx, invs)),
                  _module_name(bwd.lower(x, solver.fronts, idx, invs)))
         assert names == ("jit_solve_fwd", "jit_solve_bwd")
 
 
-def test_device_solve_builds_are_censused_per_sweep():
+def test_device_solve_builds_are_censused_per_sweep(fresh_solve_programs):
     """A new solver's first solve records one build per sweep program
     (``solve`` site, keyed by program and nrhs bucket); its next solve
     builds nothing."""
@@ -238,7 +245,7 @@ def test_device_solve_builds_are_censused_per_sweep():
     assert COMPILE_STATS.marker() == m1
 
 
-def test_refinement_spmv_build_is_censused():
+def test_refinement_spmv_build_is_censused(fresh_solve_programs):
     """A DeviceSpMV's first product is a ``spmv`` build; later products
     with the same signature are not."""
     from superlu_dist_tpu.parallel.dist import DeviceSpMV
@@ -252,6 +259,23 @@ def test_refinement_spmv_build_is_censused():
     assert recs[0].key.startswith("refine_spmv ")
     op.matvec(np.ones(a.n_rows))
     assert COMPILE_STATS.marker() == m0 + 1
+
+
+def test_refinement_spmv_outlives_its_matrix(fresh_solve_programs):
+    """Two operators on one pattern with different values build
+    ``refine_spmv`` once; each product is its own matrix's."""
+    from superlu_dist_tpu.parallel.dist import DeviceSpMV
+    a = poisson2d(5)
+    a2 = type(a)(a.n_rows, a.n_cols, a.indptr, a.indices,
+                 a.data * np.linspace(1.0, 2.0, a.nnz))
+    x = np.random.default_rng(2).standard_normal(a.n_rows)
+    m0 = COMPILE_STATS.marker()
+    for m in (a, a2):
+        op = DeviceSpMV(m)
+        np.testing.assert_allclose(op.matvec(x), m.matvec(x), rtol=1e-13,
+                                   atol=1e-13)
+    recs = COMPILE_STATS.records[m0:]
+    assert [r.key.split()[0] for r in recs] == ["refine_spmv"]
 
 
 @pytest.mark.parametrize("event,hit", [
@@ -281,14 +305,23 @@ def test_persistent_hit_from_jax_events(event, hit):
 
 def test_first_call_builds_once_per_signature():
     """``compilestats.call``: the first call per argument signature is a
-    build; a new signature is another."""
+    build; a new signature is another.  ``tally`` counts this thread's
+    calls in its body as misses (builds) and hits."""
+    from superlu_dist_tpu.obs.compilestats import tally
     fn = jax.jit(lambda v: v * 2)
     m0 = COMPILE_STATS.marker()
-    call("test.call", "double", fn, jnp.ones(3))
-    call("test.call", "double", fn, jnp.ones(3))
-    assert COMPILE_STATS.marker() == m0 + 1
+    with tally() as programs:
+        call("test.call", "double", fn, jnp.ones(3))
+        call("test.call", "double", fn, jnp.ones(3))
+        assert COMPILE_STATS.marker() == m0 + 1
+        t = threading.Thread(target=call,
+                             args=("test.call", "double", fn, jnp.ones(3)))
+        t.start()
+        t.join()
+    assert (programs.hits, programs.misses) == (1, 1)
     call("test.call", "double", fn, jnp.ones(4))
     assert COMPILE_STATS.marker() == m0 + 2
+    assert (programs.hits, programs.misses) == (1, 1)
 
 
 def _span_report():
